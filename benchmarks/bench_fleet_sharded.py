@@ -2,9 +2,9 @@
 throughput as the cell population scales across a ``('fleet',)`` mesh,
 and the cost of the two topology-aggregation modes.
 
-Measurements (all on a forced multi-device CPU host platform, so the
-numbers exercise real SPMD partitioning + collectives, not accelerator
-speed):
+Measurements, on the devices of the process it runs in (a TPU host's
+chips, or a multi-device CPU host platform forced by the caller, whose
+numbers exercise SPMD partitioning + collectives, not device speed):
 
 * ``sharded_env_steps``     — cell-steps/sec of the jitted fleet env
   step with scenario + Q-state sharded along cells, at fleet sizes
@@ -18,32 +18,24 @@ speed):
   under GSPMD on an unconstrained assignment (the compiler's
   cross-device reduction).
 
-When invoked directly this script forces
-``--xla_force_host_platform_device_count=8`` before jax initializes;
-when imported by ``benchmarks/run.py`` (where jax is already live on
-one device) ``main()`` relaunches itself as a subprocess and folds the
-child's metrics back into ``results/BENCH_fleet.json``.
+It starts no child process: one process holds the devices. On a CPU
+host, force several devices from the environment before JAX starts,
+e.g. ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; with one
+device the mesh has one shard.
 
 ``--tiny`` (CLI) shrinks every budget to a few seconds of work — the CI
 smoke mode that keeps this script from rotting.
 """
-import json
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-_FORCE = "--xla_force_host_platform_device_count"
-if __name__ == "__main__" and _FORCE not in os.environ.get("XLA_FLAGS", ""):
-    # must happen before jax initializes (it locks the device count)
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + f" {_FORCE}=8"
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import FAST, RESULTS_DIR, Timer, emit, save_json
+from benchmarks.common import FAST, Timer, emit, save_json
 from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning,
                          SyntheticSource, init_fleet, make_fleet_env_step,
                          shard, topology)
@@ -203,28 +195,7 @@ def _run(tiny: bool) -> dict:
 
 
 def main(tiny: bool = False) -> dict:
-    if jax.device_count() > 1:
-        return _run(tiny)
-    if os.environ.get("REPRO_SHARDED_BENCH_CHILD"):
-        # we ARE the relaunched child and the device count is still 1:
-        # forcing the host platform had no effect (e.g. jax defaults to
-        # a single-accelerator backend here) — fail loudly instead of
-        # relaunching forever
-        raise RuntimeError(
-            "forced host platform still reports 1 device; run with "
-            f"JAX_PLATFORMS=cpu XLA_FLAGS='{_FORCE}=8' to benchmark the "
-            "sharded fleet on this machine")
-    # jax already initialized single-device (benchmarks.run imports every
-    # suite) — relaunch so the forced host platform takes effect
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + f" {_FORCE}=8"
-    env["REPRO_SHARDED_BENCH_CHILD"] = "1"
-    cmd = [sys.executable, os.path.abspath(__file__)]
-    if tiny:
-        cmd.append("--tiny")
-    subprocess.run(cmd, env=env, check=True)
-    with open(os.path.join(RESULTS_DIR, "fleet_sharded.json")) as f:
-        return json.load(f)
+    return _run(tiny)
 
 
 if __name__ == "__main__":

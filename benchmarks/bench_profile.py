@@ -17,27 +17,19 @@ Measurements:
   cliff as runtime overhead vs algorithmic growth — the diagnosis the
   ROADMAP's "Million-cell fleets" flatness item asks for.
 
-Like ``bench_fleet_sharded``, invoking this file directly forces
-``--xla_force_host_platform_device_count=8`` before jax initializes;
-when imported by ``benchmarks/run.py`` (jax already live on one
-device) ``main()`` relaunches itself as a subprocess and folds the
-child's metrics back in. ``--tiny`` is the CI smoke mode.
+Like ``bench_fleet_sharded``, it runs on the devices of its own process
+and starts no child: the sharded sweep runs when the caller's
+environment gives more than one device. ``--tiny`` is the CI smoke
+mode.
 """
-import json
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-_FORCE = "--xla_force_host_platform_device_count"
-if __name__ == "__main__" and _FORCE not in os.environ.get("XLA_FLAGS", ""):
-    # must happen before jax initializes (it locks the device count)
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + f" {_FORCE}=8"
-
 import jax
 
-from benchmarks.common import FAST, RESULTS_DIR, emit, save_json
+from benchmarks.common import FAST, emit, save_json
 from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning, shard)
 from repro.fleet.api import SyntheticSource
 from repro.fleet.policy import FleetDQN, FleetDQNConfig
@@ -125,24 +117,7 @@ def _run(tiny: bool) -> dict:
 
 
 def main(tiny: bool = False) -> dict:
-    if jax.device_count() > 1:
-        return _run(tiny)
-    if os.environ.get("REPRO_PROFILE_BENCH_CHILD"):
-        raise RuntimeError(
-            "forced host platform still reports 1 device; run with "
-            f"JAX_PLATFORMS=cpu XLA_FLAGS='{_FORCE}=8' to profile the "
-            "sharded sweep on this machine")
-    # jax already initialized single-device (benchmarks.run imports every
-    # suite) — relaunch so the forced host platform takes effect
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + f" {_FORCE}=8"
-    env["REPRO_PROFILE_BENCH_CHILD"] = "1"
-    cmd = [sys.executable, os.path.abspath(__file__)]
-    if tiny:
-        cmd.append("--tiny")
-    subprocess.run(cmd, env=env, check=True)
-    with open(os.path.join(RESULTS_DIR, "bench_profile.json")) as f:
-        return json.load(f)
+    return _run(tiny)
 
 
 if __name__ == "__main__":

@@ -32,6 +32,7 @@ from benchmarks import (bench_adaptation, bench_bridge,
                         bench_table10_sota, bench_table11_convergence,
                         bench_topology, bench_trace_replay)
 from benchmarks.common import save_json
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig1": bench_fig1_motivation,
@@ -67,6 +68,7 @@ def main() -> None:
                     help="write results/BENCH_fleet.json (fleet headline "
                          "metrics; implies running the fleet suites)")
     args = ap.parse_args()
+    enable_compile_cache()
     names = list(SUITES) if not args.only else args.only.split(",")
     if args.json:
         names += [n for n in FLEET_SUITES if n not in names]

@@ -352,6 +352,11 @@ class FleetDQN:
         self._op_impl = resolved
         self._op_kwargs = (None if resolved == "xla"
                            else ops.rl_op_kwargs(resolved))
+        #: where each part of the act head runs: the fused head splits
+        #: kernel and XLA work (``ops.dqn_head_parts``)
+        self.op_parts = ({"head": "xla"} if resolved == "xla" else
+                         ops.dqn_head_parts(resolved,
+                                            self.cfg.accuracy_threshold))
         self.opt = init_opt_state(self.params)
         self.buffer = replay_init(self.cfg.replay_capacity, self.state_dim,
                                   action_shape=(users,))

@@ -1,35 +1,41 @@
-"""Fused featurize + constraint-aware greedy head Pallas TPU kernel.
+"""Fused featurize + masked Q head + per-user top-k Pallas TPU kernel.
 
 ``FleetDQN``'s act path materializes the flat ``encode_fleet_state``
 vector, re-slices it back into per-user rows, runs the shared MLP, and
-— with a QoS goal — gathers ``(cells, topk^N, N)`` candidate tensors to
-filter the per-user top-k combinations by the accuracy ladder. This
-kernel fuses the whole head per fleet block: each grid program
-assembles the ``(BC * N, 11)`` per-user feature matrix directly from
-the ``active``/``member``/``end_b`` blocks plus the 8-wide cell
-aggregates, keeps the three MLP weight matrices resident in VMEM
-across the block, masks with the allowed-action table, and resolves
-the constraint head in-register — top-k as ``k`` (max, first-argmax,
-mask) reduce pairs, combo scoring via compile-time-static gathers of
-the ``(topk^N, N)`` combination table, accuracy lookup as a one-hot
-contraction against the ladder — emitting only the ``(BC, N)`` greedy
-decisions and the masked head values.
+— with a QoS goal — takes each user's top-k actions before scoring
+the ``topk^N`` combinations against the accuracy ladder. This kernel
+fuses the per-user part per fleet block: each grid program assembles
+the ``(BC * N, 11)`` per-user feature matrix directly from the
+``active``/``member``/``end_b`` blocks plus the 8-wide cell aggregates,
+keeps the three MLP weight matrices resident in VMEM across the block,
+masks with the allowed-action table, and emits the plain per-user
+greedy decisions, the masked head values and — with a QoS goal — the
+top-k values and ids as ``k`` (max, first-argmax, mask) reduce pairs.
 
-Combos with a masked (NEG_INF) member entry are culled, infeasible
-combos are culled, and a cell with no feasible combo falls back to the
-plain per-user argmax — bit-identical decision semantics to
-``ref.dqn_head_ref`` (the PR-2 constraint-leak fix, re-pinned here).
+The combination scoring (``ref.combo_pick``: ``(cells, topk^N)``
+candidates, 3,125 at 5 users and ``topk=5``) runs outside the kernel
+in XLA. Unrolled in the kernel it needs more scoped VMEM than a v5e
+core has at a useful block size and does not compile in bounded time.
+``kernels.ops.dqn_head_parts`` names the split, and the agents report
+it next to the resolved impl.
 """
 from __future__ import annotations
 
 import functools
-import itertools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
+#: the head's matmuls run at full f32 precision on both the kernel and
+#: the oracle (XLA's TPU default for f32 is one bf16 pass, Mosaic's is
+#: not), so decisions agree across the two paths on the chip
+HIGHEST = jax.lax.Precision.HIGHEST
+
+#: scoped-VMEM budget the block size is chosen against: v5e's 16 MiB
+#: default scoped limit, less headroom for Mosaic's own scratch
+VMEM_BUDGET = 12 * 2 ** 20
 
 
 def _first_argmax(x, iota):
@@ -39,9 +45,34 @@ def _first_argmax(x, iota):
     return jnp.min(jnp.where(x == m, iota, k), axis=-1).astype(jnp.int32)
 
 
+def block_cells(users: int, hidden: int, n_act: int, topk: int,
+                budget: int = VMEM_BUDGET) -> int:
+    """Largest fleet block (a multiple of 8, at most 512 cells) whose
+    kernel working set fits ``budget`` bytes of scoped VMEM.
+
+    A conservative count per cell, in f32 (8, 128)-tiled layout: the
+    ``(N, 11)`` feature rows and two ``(N, hidden)`` activations as
+    ``N``-row slabs; the ``(N, A)`` head values, the top-k working copy
+    and ``2 * topk`` top-k planes as ``(N -> 8, A -> 128)`` tiles; and
+    every in/out block as such tiles, double-buffered. The resident MLP
+    weights come off the budget first."""
+    lanes = lambda n: -(-n // 128) * 128
+    rows = -(-users // 8) * 8
+    f32 = 4
+    per_cell = f32 * (users * (lanes(11) + 2 * lanes(hidden))
+                      + rows * lanes(n_act) * (4 + 2 * topk)
+                      + 2 * 2 * (4 * rows * lanes(1)
+                                 + rows * lanes(n_act)
+                                 + 2 * rows * lanes(topk)))
+    weights = 2 * f32 * (lanes(hidden) * (16 + hidden) + lanes(n_act)
+                         * (hidden + 8) + 2 * 8 * lanes(hidden))
+    bc = (budget - weights) // per_cell
+    return int(max(8, min(512, bc // 8 * 8)))
+
+
 def _kernel(act_ref, mem_ref, end_ref, agg_ref, w1_ref, b1_ref, w2_ref,
-            b2_ref, w3_ref, b3_ref, mask_ref, acc_ref, combo_ref, dec_ref,
-            q_ref, *, bc: int, users: int, threshold: float, topk: int):
+            b2_ref, w3_ref, b3_ref, mask_ref, dec_ref, q_ref, *topk_refs,
+            bc: int, users: int, topk: int):
     n = users
     act = act_ref[...]                                    # (BC, N)
     agg = agg_ref[...]                                    # (BC, 8)
@@ -49,19 +80,21 @@ def _kernel(act_ref, mem_ref, end_ref, agg_ref, w1_ref, b1_ref, w2_ref,
         [act[..., None], mem_ref[...][..., None], end_ref[...][..., None],
          jnp.broadcast_to(agg[:, None, :], (bc, n, agg.shape[-1]))], -1)
     x = feats.reshape(bc * n, feats.shape[-1])
-    h = jnp.maximum(jnp.dot(x, w1_ref[...]) + b1_ref[...], 0.0)
-    h = jnp.maximum(jnp.dot(h, w2_ref[...]) + b2_ref[...], 0.0)
-    q = jnp.dot(h, w3_ref[...]) + b3_ref[...]
+    dot = functools.partial(jnp.dot, precision=HIGHEST)
+    h = jnp.maximum(dot(x, w1_ref[...]) + b1_ref[...], 0.0)
+    h = jnp.maximum(dot(h, w2_ref[...]) + b2_ref[...], 0.0)
+    q = dot(h, w3_ref[...]) + b3_ref[...]
     n_act = q.shape[-1]
     q = jnp.where(mask_ref[...][None] > 0.5, q.reshape(bc, n, n_act),
                   NEG_INF)                                # (BC, N, A)
     q_ref[...] = q
     iota_a = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_act), 2)
-    plain = _first_argmax(q, iota_a)                      # (BC, N)
-    if not threshold:
-        dec_ref[...] = plain
+    dec_ref[...] = _first_argmax(q, iota_a)               # (BC, N)
+    if not topk_refs:
         return
-    # --- stable top-k: k rounds of (max, first-argmax, mask-out) ------
+    # stable top-k: k rounds of (max, first-argmax, mask-out); an
+    # exhausted row re-yields NEG_INF, culled by ref.combo_pick
+    vals_ref, idx_ref = topk_refs
     vals, idx, cur = [], [], q
     for _ in range(topk):
         i = _first_argmax(cur, iota_a)
@@ -70,81 +103,40 @@ def _kernel(act_ref, mem_ref, end_ref, agg_ref, w1_ref, b1_ref, w2_ref,
                     + jnp.where(jnp.all(~hit, -1), NEG_INF, 0.0))
         idx.append(i)
         cur = jnp.where(hit, NEG_INF, cur)
-    vals = jnp.stack(vals, -1)                            # (BC, N, k)
-    idx = jnp.stack(idx, -1)
-    # accuracy ladder lookup as a one-hot contraction (no gathers)
-    acc = acc_ref[...]                                    # (1, A)
-    onehot = idx[..., None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, n_act), 3)                   # (BC, N, k, A)
-    acc_k = jnp.sum(jnp.where(onehot, acc[None, None], 0.0), -1)
-    # --- combo scoring over the (topk^N, N) table ---------------------
-    # Per-user column gathers of the combos ref, expressed as one-hot
-    # contractions against the candidate axis (Pallas rejects captured
-    # numpy index constants, and gathers don't vectorize anyway).
-    comb = combo_ref[...]                                 # (Kc, N) int32
-    n_combo = comb.shape[0]
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, 1, topk), 2)
-    mem = mem_ref[...] > 0.5
-    nm = jnp.maximum(jnp.sum(mem.astype(q.dtype), -1), 1.0)[:, None]
-    score = jnp.zeros((bc, n_combo), q.dtype)
-    macc_sum = jnp.zeros((bc, n_combo), q.dtype)
-    invalid = jnp.zeros((bc, n_combo), jnp.bool_)
-    sel_idx = []
-    for u in range(n):
-        oh_u = comb[:, u][None, :, None] == iota_k        # (1, Kc, k)
-        v_u = jnp.sum(jnp.where(oh_u, vals[:, u][:, None, :], 0.0), -1)
-        a_u = jnp.sum(jnp.where(oh_u, acc_k[:, u][:, None, :], 0.0), -1)
-        i_u = jnp.sum(jnp.where(oh_u, idx[:, u][:, None, :], 0), -1)
-        m_u = mem[:, u:u + 1]
-        score = score + jnp.where(m_u, v_u, 0.0)
-        macc_sum = macc_sum + jnp.where(m_u, a_u, 0.0)
-        invalid = invalid | ((v_u < -1e29) & m_u)
-        sel_idx.append(i_u)                     # (BC, Kc) candidate ids
-    macc = jnp.where(jnp.any(mem, -1, keepdims=True), macc_sum / nm,
-                     100.0)
-    feas = macc >= threshold - 1e-9             # dynamics.feasible
-    score = jnp.where(feas & ~invalid, score, -jnp.inf)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, n_combo), 1)
-    j = _first_argmax(score, iota_c)                      # (BC,)
-    pick = iota_c == j[:, None]                           # (BC, Kc)
-    best = jnp.stack(
-        [jnp.sum(jnp.where(pick, i_u, 0), -1) for i_u in sel_idx], -1)
-    has_feasible = jnp.isfinite(jnp.max(score, -1))
-    dec_ref[...] = jnp.where(has_feasible[:, None], best, plain)
+    vals_ref[...] = jnp.stack(vals, -1)                   # (BC, N, k)
+    idx_ref[...] = jnp.stack(idx, -1)
 
 
 def dqn_head_kernel(active, member, end_b, agg, w1, b1, w2, b2, w3, b3,
-                    allowed, acc_table, *, threshold: float, topk: int,
-                    bc: int = 128, interpret: bool = True):
+                    allowed, *, topk: int, bc: int, interpret: bool = True):
     """active/member/end_b: (cells, N) f32, cells a multiple of ``bc``;
     agg: (cells, 8) f32; w*/b*: the 3-layer shared MLP (biases shaped
-    (1, width)); allowed: (N, A) f32 0/1 mask; acc_table: (1, A) f32.
-    Returns ``(dec, q)``: (cells, N) int32 and (cells, N, A) f32;
-    semantics of ``ref.dqn_head_ref``."""
+    (1, width)); allowed: (N, A) f32 0/1 mask. ``topk=0`` skips the
+    top-k planes. Returns ``(plain, q)`` — (cells, N) int32 per-user
+    argmax and (cells, N, A) f32 masked values — plus, for ``topk > 0``,
+    ``(vals, idx)``: (cells, N, topk) f32 / int32, descending values
+    with ties by ascending id (the ``jax.lax.top_k`` order)."""
     cells, users = active.shape
     n_act = w3.shape[1]
-    grid = (cells // bc,)
-    combos = jnp.asarray(
-        list(itertools.product(range(topk), repeat=users)), jnp.int32)
-    kernel = functools.partial(_kernel, bc=bc, users=users,
-                               threshold=threshold, topk=topk)
+    kernel = functools.partial(_kernel, bc=bc, users=users, topk=topk)
     user_spec = pl.BlockSpec((bc, users), lambda i: (i, 0))
     full = [pl.BlockSpec(arr.shape, lambda i: (0,) * arr.ndim)
-            for arr in (w1, b1, w2, b2, w3, b3, allowed, acc_table,
-                        combos)]
-    dec, q = pl.pallas_call(
+            for arr in (w1, b1, w2, b2, w3, b3, allowed)]
+    per_user = lambda w: pl.BlockSpec((bc, users, w), lambda i: (i, 0, 0))
+    out_specs = [user_spec, per_user(n_act)]
+    out_shape = [jax.ShapeDtypeStruct((cells, users), jnp.int32),
+                 jax.ShapeDtypeStruct((cells, users, n_act), jnp.float32)]
+    if topk:
+        out_specs += [per_user(topk), per_user(topk)]
+        out_shape += [jax.ShapeDtypeStruct((cells, users, topk), jnp.float32),
+                      jax.ShapeDtypeStruct((cells, users, topk), jnp.int32)]
+    return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(cells // bc,),
         in_specs=[user_spec, user_spec, user_spec,
                   pl.BlockSpec((bc, agg.shape[1]), lambda i: (i, 0)),
                   *full],
-        out_specs=[user_spec,
-                   pl.BlockSpec((bc, users, n_act), lambda i: (i, 0, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((cells, users), jnp.int32),
-            jax.ShapeDtypeStruct((cells, users, n_act), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(active, member, end_b, agg, w1, b1, w2, b2, w3, b3, allowed,
-      acc_table, combos)
-    return dec, q
+    )(active, member, end_b, agg, w1, b1, w2, b2, w3, b3, allowed)

@@ -8,13 +8,14 @@ is also the path the SPMD dry-run lowers (see DESIGN.md §3).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_kernel
-from repro.kernels.dqn_head import dqn_head_kernel
+from repro.kernels.dqn_head import block_cells, dqn_head_kernel
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.int8_matmul import int8_matmul_kernel
 from repro.kernels.selective_scan import selective_scan_kernel
@@ -173,6 +174,18 @@ def rl_op_kwargs(resolved: str) -> dict:
     raise ValueError(f"no fused op path for resolved impl {resolved!r}")
 
 
+def dqn_head_parts(resolved: str, threshold: float) -> dict:
+    """Where each part of the fused DQN head runs for a
+    ``resolve_rl_impl`` result: on the kernel path the featurize + MLP +
+    mask + top-k part is the Pallas kernel and, with a QoS goal, the
+    ``topk^N`` combination scoring (``ref.combo_pick``) is XLA."""
+    kernel = resolved in ("pallas", "pallas_interpret")
+    parts = {"head": resolved}
+    if threshold:
+        parts["combo_pick"] = "xla" if kernel else resolved
+    return parts
+
+
 @functools.partial(jax.jit, static_argnames=("alpha", "gamma", "impl",
                                              "bc", "interpret"))
 def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
@@ -186,7 +199,7 @@ def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
                                      gamma=gamma)
     cells = q.shape[0]
     q_, _ = _pad_to(q, 0, bc)
-    cols = [_pad_to(x[:, None], 0, bc)[0] for x in (s, a, r, s2)]
+    cols = [_pad_to(x, 0, bc)[0] for x in (s, a, r, s2)]
     q_new, greedy2, td = tabular_rl_kernel(
         q_, *cols, alpha=alpha, gamma=gamma, bc=bc, interpret=interpret)
     return q_new[:cells], greedy2[:cells, 0], td[:cells, 0]
@@ -196,14 +209,17 @@ def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
                                              "bc", "interpret"))
 def dqn_head(active, member, end_b, agg, params, allowed, acc_table, *,
              threshold: float, topk: int, impl: str = "ref",
-             bc: int = 128, interpret: bool = True):
+             bc: Optional[int] = None, interpret: bool = True):
     """Fused featurize + constraint-aware greedy head.
 
     active/member/end_b: (cells, N) f32; agg: (cells, 8) f32 cell
     aggregates; params: the 3-layer shared-net param list
     (``[{"w", "b"}] * 3``); allowed: (N, A) bool allowed-action mask;
     acc_table: (A,) f32 accuracy ladder. Returns ``(dec, q)``; see
-    ``ref.dqn_head_ref``.
+    ``ref.dqn_head_ref``. On the kernel path the head and top-k run in
+    the Pallas kernel and, with a QoS ``threshold``, the combination
+    scoring in XLA (``dqn_head_parts``); ``bc`` defaults to the largest
+    block that fits the kernel's VMEM budget (``block_cells``).
     """
     (w1, b1), (w2, b2), (w3, b3) = [(p["w"], p["b"].reshape(1, -1))
                                     for p in params]
@@ -212,13 +228,16 @@ def dqn_head(active, member, end_b, agg, params, allowed, acc_table, *,
         return ref.dqn_head_ref(active, member, end_b, agg, w1, b1, w2,
                                 b2, w3, b3, allowed_f, acc_table,
                                 threshold=threshold, topk=topk)
-    cells = active.shape[0]
-    act_, _ = _pad_to(active, 0, bc)
-    mem_, _ = _pad_to(member, 0, bc)
-    end_, _ = _pad_to(end_b, 0, bc)
-    agg_, _ = _pad_to(agg, 0, bc)
-    dec, q = dqn_head_kernel(act_, mem_, end_, agg_, w1, b1, w2, b2, w3,
-                             b3, allowed_f, acc_table[None, :],
-                             threshold=threshold, topk=topk, bc=bc,
-                             interpret=interpret)
-    return dec[:cells], q[:cells]
+    cells, users = active.shape
+    k = topk if threshold else 0          # top-k planes only for the goal
+    if bc is None:
+        bc = block_cells(users, w1.shape[1], w3.shape[1], k)
+    padded = [_pad_to(x, 0, bc)[0] for x in (active, member, end_b, agg)]
+    outs = dqn_head_kernel(*padded, w1, b1, w2, b2, w3, b3, allowed_f,
+                           topk=k, bc=bc, interpret=interpret)
+    plain, q = outs[0][:cells], outs[1][:cells]
+    if not threshold:
+        return plain, q
+    vals, idx = outs[2][:cells], outs[3][:cells]
+    return ref.combo_pick(vals, idx, member, plain, acc_table,
+                          threshold=threshold), q

@@ -2,10 +2,13 @@
 tests/test_kernels.py shape/dtype sweeps)."""
 from __future__ import annotations
 
+import functools
+import itertools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 
@@ -115,23 +118,6 @@ def fused_tabular_ref(q, s, a, r, s2, *, alpha: float, gamma: float):
     return q_new, greedy2, td
 
 
-def _stable_topk_ref(q, k):
-    """Iterative (max, first-argmax, mask) top-k: values descending,
-    ties by ascending index — the ordering ``jax.lax.top_k`` produces —
-    expressed as k vectorized reduce pairs so the same loop lowers
-    inside the Pallas kernel. Exhausted rows re-yield ``NEG_INF``
-    values (always culled by the invalid filter downstream)."""
-    iota = jnp.arange(q.shape[-1], dtype=jnp.int32)
-    iota = iota.reshape((1,) * (q.ndim - 1) + (-1,))
-    vals, idx, cur = [], [], q
-    for _ in range(k):
-        i = first_argmax_ref(cur)
-        vals.append(jnp.take_along_axis(cur, i[..., None], -1)[..., 0])
-        idx.append(i)
-        cur = jnp.where(iota == i[..., None], NEG_INF, cur)
-    return jnp.stack(vals, -1), jnp.stack(idx, -1)
-
-
 def dqn_head_ref(active, member, end_b, agg, w1, b1, w2, b2, w3, b3,
                  allowed, acc_table, *, threshold: float, topk: int):
     """Fused featurize + constraint-aware greedy head oracle.
@@ -148,11 +134,8 @@ def dqn_head_ref(active, member, end_b, agg, w1, b1, w2, b2, w3, b3,
     Assembles each user's ``[act, mem, end, agg...]`` feature row
     directly (never materializing the flat ``encode_fleet_state``
     vector), applies the shared MLP, masks, and — with a QoS
-    ``threshold`` — scores the per-user top-k combinations against the
-    accuracy ladder exactly like ``FleetDQN._make_greedy``: combos with
-    a masked (NEG_INF) member entry are culled, infeasible combos are
-    culled, and a cell with no feasible combo falls back to the plain
-    per-user argmax.
+    ``threshold`` — picks among the per-user top-k combinations with
+    ``combo_pick``.
     """
     cells, n = active.shape
     feats = jnp.concatenate(
@@ -160,29 +143,44 @@ def dqn_head_ref(active, member, end_b, agg, w1, b1, w2, b2, w3, b3,
          jnp.broadcast_to(agg[:, None, :],
                           (cells, n, agg.shape[-1]))], -1)
     x = feats.reshape(cells * n, feats.shape[-1])
-    h = jax.nn.relu(x @ w1 + b1)
-    h = jax.nn.relu(h @ w2 + b2)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    h = jax.nn.relu(dot(x, w1) + b1)
+    h = jax.nn.relu(dot(h, w2) + b2)
     q = jnp.where(allowed[None] > 0.5,
-                  (h @ w3 + b3).reshape(cells, n, -1), NEG_INF)
+                  (dot(h, w3) + b3).reshape(cells, n, -1), NEG_INF)
     plain = first_argmax_ref(q)
     if not threshold:
         return plain, q
-    import itertools
-    import numpy as np
-    k = topk
-    # lax.top_k has the exact ordering _stable_topk_ref reproduces
-    # in-kernel (descending values, ties by ascending index); on rows
-    # with fewer than k finite entries the two diverge only in
-    # duplicated NEG_INF candidate ids, which the invalid filter below
-    # culls on both paths — decisions stay bit-identical
-    vals, idx = jax.lax.top_k(q, k)                    # (cells, N, k)
+    # lax.top_k has the exact ordering of the kernel's iterative top-k
+    # (descending values, ties by ascending index); on rows with fewer
+    # than k finite entries the two diverge only in duplicated NEG_INF
+    # candidate ids, which combo_pick culls on both paths — decisions
+    # stay bit-identical
+    vals, idx = jax.lax.top_k(q, topk)                 # (cells, N, k)
+    return combo_pick(vals, idx, member, plain, acc_table,
+                      threshold=threshold), q
+
+
+def combo_pick(vals, idx, member, plain, acc_table, *, threshold: float):
+    """Constraint-aware pick among the per-user top-k combinations,
+    exactly like ``FleetDQN._make_greedy``'s legacy head.
+
+    ``vals``/``idx``: (cells, N, k) per-user top-k values and action
+    ids; ``member``: (cells, N) f32/bool; ``plain``: (cells, N) plain
+    per-user argmax; ``acc_table``: (A,) accuracy ladder. Scores all
+    ``k^N`` combinations: combos with a masked (NEG_INF) member entry
+    are culled, combos whose mean member accuracy misses ``threshold``
+    are culled, and a cell with no feasible combo falls back to
+    ``plain``. Shared by the oracle and the kernel path, which computes
+    ``vals``/``idx`` in the Pallas kernel."""
+    cells, n, k = vals.shape
     acc_k = acc_table[idx]                             # (cells, N, k)
     combos = np.asarray(list(itertools.product(range(k), repeat=n)),
                         np.int32)                      # (Kc, N) static
     mem = member > 0.5
-    nm = jnp.maximum(mem.sum(-1), 1)[:, None].astype(q.dtype)
-    score = jnp.zeros((cells, len(combos)), q.dtype)
-    macc_sum = jnp.zeros((cells, len(combos)), q.dtype)
+    nm = jnp.maximum(mem.sum(-1), 1)[:, None].astype(vals.dtype)
+    score = jnp.zeros((cells, len(combos)), vals.dtype)
+    macc_sum = jnp.zeros((cells, len(combos)), vals.dtype)
     invalid = jnp.zeros((cells, len(combos)), bool)
     for u in range(n):
         cu = combos[:, u]                              # static gather
@@ -199,7 +197,7 @@ def dqn_head_ref(active, member, end_b, agg, w1, b1, w2, b2, w3, b3,
     best = jnp.take_along_axis(idx, cu_j[..., None], 2)[..., 0]
     has_feasible = jnp.isfinite(
         jnp.take_along_axis(score, j[:, None], 1))[:, 0]
-    return jnp.where(has_feasible[:, None], best, plain), q
+    return jnp.where(has_feasible[:, None], best, plain)
 
 
 def selective_scan_ref(u, dt, A, B, C, D):

@@ -14,6 +14,11 @@ the post-update ``s2`` row — so the scan carries ``greedy`` instead of
 re-gathering the row, and Q-table rows never leave VMEM between the
 act and the update that consumed them.
 
+Layout for Mosaic: the per-cell indices and rewards (``s/a/r/s2``) are
+SMEM blocks, read as scalars to address the rows; ``greedy2``/``td``
+are ``(BC, 1)`` VMEM vectors carried through the cell loop and stored
+once per block (Mosaic refuses scalar stores to VMEM).
+
 Argmax is the first-index tie-break of ``jnp.argmax``, computed as a
 (max, masked index-min) reduce pair — the same trick
 ``ref.first_argmax_ref`` uses, vectorized on the VPU lanes.
@@ -25,59 +30,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(q_ref, s_ref, a_ref, r_ref, s2_ref, q_out_ref, g_ref, td_ref,
+def _kernel(s_ref, a_ref, r_ref, s2_ref, q_ref, q_out_ref, g_ref, td_ref,
             *, bc: int, alpha: float, gamma: float, n_actions: int):
-    # q: (BC, S, K); s/a/r/s2 and g/td: (BC, 1)
-    q_out_ref[...] = q_ref[...]          # no-op under aliasing; exact copy
+    # s/a/r/s2: (1, BC) SMEM; q: (BC, S, K) VMEM; g/td: (BC, 1) VMEM
+    q_out_ref[...] = q_ref[...]    # the out block is its own VMEM buffer
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_actions), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bc, 1), 0)
 
-    def cell(c, _):
-        s_c, a_c = s_ref[c, 0], a_ref[c, 0]
-        s2_c, r_c = s2_ref[c, 0], r_ref[c, 0]
-        row_s = pl.load(q_ref, (c, pl.ds(s_c, 1), slice(None)))   # (1, K)
-        row_2 = pl.load(q_ref, (c, pl.ds(s2_c, 1), slice(None)))  # (1, K)
+    def cell(c, carry):
+        g_vec, td_vec = carry
+        s_c, a_c = s_ref[0, c], a_ref[0, c]
+        s2_c, r_c = s2_ref[0, c], r_ref[0, c]
+        row_s = q_ref[c, pl.ds(s_c, 1), :]                     # (1, K)
+        row_2 = q_ref[c, pl.ds(s2_c, 1), :]                    # (1, K)
         onehot = iota == a_c
-        q_sa = jnp.sum(jnp.where(onehot, row_s, 0.0))
-        td = r_c + gamma * jnp.max(row_2) - q_sa
+        q_sa = jnp.sum(jnp.where(onehot, row_s, 0.0), -1, keepdims=True)
+        td = r_c + gamma * jnp.max(row_2, -1, keepdims=True) - q_sa
         row_s_new = row_s + jnp.where(onehot, alpha * td, 0.0)
-        pl.store(q_out_ref, (c, pl.ds(s_c, 1), slice(None)), row_s_new)
+        q_out_ref[c, pl.ds(s_c, 1), :] = row_s_new
         # next step's greedy on the POST-update s2 row (when s2 == s the
         # freshly written entry participates)
         row_2_new = jnp.where(s2_c == s_c, row_s_new, row_2)
-        m2 = jnp.max(row_2_new)
-        g = jnp.min(jnp.where(row_2_new == m2, iota, n_actions))
-        g_ref[c, 0] = g.astype(jnp.int32)
-        td_ref[c, 0] = td
-        return _
+        m2 = jnp.max(row_2_new, -1, keepdims=True)
+        g = jnp.min(jnp.where(row_2_new == m2, iota, n_actions), -1,
+                    keepdims=True)                             # (1, 1)
+        hit = rows == c
+        return jnp.where(hit, g, g_vec), jnp.where(hit, td, td_vec)
 
-    jax.lax.fori_loop(0, bc, cell, 0)
+    g_vec, td_vec = jax.lax.fori_loop(
+        0, bc, cell, (jnp.zeros((bc, 1), jnp.int32),
+                      jnp.zeros((bc, 1), jnp.float32)))
+    g_ref[...] = g_vec
+    td_ref[...] = td_vec
 
 
 def tabular_rl_kernel(q, s, a, r, s2, *, alpha: float, gamma: float,
                       bc: int = 8, interpret: bool = True):
-    """q: (cells, S, K) f32; s/a/r/s2: (cells, 1) int32/f32, cells a
-    multiple of ``bc``. Returns ``(q_new, greedy2, td)`` with greedy2/td
-    shaped (cells, 1); semantics of ``ref.fused_tabular_ref``."""
+    """q: (cells, S, K) f32; s/a/s2: (cells,) int32, r: (cells,) f32,
+    cells a multiple of ``bc``. Returns ``(q_new, greedy2, td)`` with
+    greedy2/td shaped (cells, 1); semantics of ``ref.fused_tabular_ref``."""
     cells, n_states, n_actions = q.shape
-    grid = (cells // bc,)
     kernel = functools.partial(_kernel, bc=bc, alpha=alpha, gamma=gamma,
                                n_actions=n_actions)
-    scalar_spec = pl.BlockSpec((bc, 1), lambda i: (i, 0))
+    smem = pl.BlockSpec((None, 1, bc), lambda i: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+    vec = pl.BlockSpec((bc, 1), lambda i: (i, 0))
     q_spec = pl.BlockSpec((bc, n_states, n_actions), lambda i: (i, 0, 0))
-    q_new, greedy2, td = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[q_spec, scalar_spec, scalar_spec, scalar_spec,
-                  scalar_spec],
-        out_specs=[q_spec, scalar_spec, scalar_spec],
+        grid=(cells // bc,),
+        in_specs=[smem, smem, smem, smem, q_spec],
+        out_specs=[q_spec, vec, vec],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((cells, 1), jnp.int32),
             jax.ShapeDtypeStruct((cells, 1), jnp.float32),
         ],
-        input_output_aliases={0: 0},     # update the Q slab in place
+        input_output_aliases={4: 0},     # update the Q slab in place
         interpret=interpret,
-    )(q, s, a, r, s2)
-    return q_new, greedy2, td
+    )(*(x.reshape(cells // bc, 1, bc) for x in (s, a, r, s2)), q)

@@ -2,13 +2,18 @@
 against 512 placeholder host devices; capture memory/cost/collective
 analysis for the roofline (EXPERIMENTS.md §Dry-run / §Roofline).
 
+A CPU-only tool: the placeholder devices are XLA:CPU host devices, so it
+sets ``JAX_PLATFORMS=cpu`` before JAX is imported and never touches an
+accelerator. The roofline terms use the v5e row of ``obs.prof.PEAKS``.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch yi-34b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] --out results/
 """
-# The first two lines MUST run before any other import (jax locks the
-# device count on first init):
+# These lines MUST run before any other import (jax locks the platform
+# and the device count on first init):
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
@@ -23,10 +28,12 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.distributed import sharding
-from repro.launch.mesh import (HBM_BW, ICI_BW_PER_LINK, PEAK_BF16_FLOPS,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh
 from repro.models import build_model
+from repro.obs.prof import PEAKS
 from repro.training import AdamWConfig, init_opt_state, make_train_step
+
+V5E = PEAKS["TPU v5 lite"]
 
 # skip list (DESIGN.md §4): pure full-attention archs have no sub-quadratic
 # path for 524k decode.
@@ -196,7 +203,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
             # pass 2 (unrolled): exact per-op cost/collective accounting
             compiled = (_lower_compile(arch, shape_name, True)[0]
                         if costs else compiled_scan)
-        ca = compiled.cost_analysis() or {}
+        ca = compiled.cost_analysis()
         ma = compiled_scan.memory_analysis()
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
@@ -214,9 +221,10 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
             bytes_per_device=bytes_dev,
             collective_bytes_per_device=coll_dev,
             collectives={k: int(v) for k, v in coll.items()},
-            compute_s=flops_dev / PEAK_BF16_FLOPS,
-            memory_s=bytes_dev / HBM_BW,
-            collective_s=coll_dev / ICI_BW_PER_LINK,
+            compute_s=flops_dev / V5E.flops_per_s,
+            memory_s=bytes_dev / V5E.bytes_per_s,
+            # a ring collective drives one of the chip's four ICI links
+            collective_s=coll_dev / (V5E.ici_bytes_per_s / 4),
             model_flops_per_device=meta["model_flops"] / n_dev,
             useful_flops_ratio=(meta["model_flops"] / n_dev) / max(flops_dev, 1.0),
             arg_bytes_per_device=getattr(ma, "argument_size_in_bytes", None),

@@ -29,8 +29,3 @@ def make_tier_mesh(tier: str):
     return jax.make_mesh(shape, ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
-
-# TPU v5e hardware constants for the roofline (per chip)
-PEAK_BF16_FLOPS = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW_PER_LINK = 50e9            # B/s per link

@@ -7,11 +7,12 @@ request traffic (the paper's Fig. 4 runtime, reduced scale on CPU).
 from __future__ import annotations
 
 import argparse
-import time
+import zlib
 
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.core import (EXPERIMENTS, EndEdgeCloudEnv, QLearningAgent,
                         IntelligentOrchestrator, train_agent)
@@ -25,7 +26,9 @@ def build_engines(cfg, variants=("d0", "d4", "d7"), max_len=64, hop_ms=None):
 
     ``hop_ms`` (e.g. ``{"E": 25.0, "C": 50.0}``) adds a real per-batch
     network-hop sleep per tier — tier SEPARATION emulation on a single
-    host (see ``ServingEngine``); default: no hops (local tiers)."""
+    host (see ``ServingEngine``); default: no hops (local tiers).
+    Weights are random, seeded by the variant id, so every process
+    builds the same engines."""
     ladder = build_ladder(cfg)
     engines = {"S": {}, "E": {}, "C": {}}
     scales = {"S": 1.0, "E": 2.0, "C": 4.0}
@@ -33,7 +36,7 @@ def build_engines(cfg, variants=("d0", "d4", "d7"), max_len=64, hop_ms=None):
     for vid in variants:
         vcfg = ladder[vid].cfg
         model = build_model(vcfg)
-        params = model.init(jax.random.PRNGKey(hash(vid) % 2**31))
+        params = model.init(jax.random.PRNGKey(zlib.crc32(vid.encode())))
         for tier, sc in scales.items():
             if tier != "S" and vid != "d0":
                 continue  # paper: edge/cloud always run d0
@@ -51,6 +54,7 @@ def main(argv=None):
     ap.add_argument("--threshold", type=float, default=85.0)
     ap.add_argument("--train-steps", type=int, default=6000)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch)) if args.arch != "edge-ladder" \
         else get_config(args.arch)

@@ -27,7 +27,7 @@ The package imports only jax/numpy/stdlib; every other layer may import
 it (see docs/ARCHITECTURE.md layering rules).
 """
 from repro.obs.metrics import MetricDef, MetricsAccumulator
-from repro.obs.prof import (BackendPeaks, CostProfile, backend_peaks,
+from repro.obs.prof import (CostProfile, DevicePeaks, device_peaks,
                             profile_fn, scaling_sweep, stage_costs)
 from repro.obs.report import (attach_manifest, config_hash, flatten,
                               rel_diff, run_manifest)
@@ -36,16 +36,16 @@ from repro.obs.timeline import (QUANTILES, attainment, exact_quantiles,
                                 hist_quantiles, quantile_key, window_series)
 
 __all__ = [
-    "BackendPeaks",
     "CostProfile",
+    "DevicePeaks",
     "MetricDef",
     "MetricsAccumulator",
     "QUANTILES",
     "SpanRecorder",
     "attach_manifest",
     "attainment",
-    "backend_peaks",
     "config_hash",
+    "device_peaks",
     "exact_quantiles",
     "flatten",
     "hist_quantiles",

@@ -10,7 +10,7 @@ fleet program gets the treatment:
 
 * :class:`CostProfile` / :func:`profile_fn` — flops, bytes accessed,
   temp/arg/output bytes, arithmetic intensity, and the roofline terms
-  (``compute_s`` / ``memory_s`` / ``dominant``) against per-backend
+  (``compute_s`` / ``memory_s`` / ``dominant``) against per-device-kind
   peak constants. No execution happens: the numbers come out of the
   compiled executable, so they are deterministic across runs and
   machines with the same compiler.
@@ -46,40 +46,38 @@ from repro.obs.spans import SpanRecorder, span
 
 
 @dataclasses.dataclass(frozen=True)
-class BackendPeaks:
-    """Peak rates the roofline terms are computed against."""
+class DevicePeaks:
+    """Peak rates of one device, which the roofline terms are computed
+    against, and where they come from."""
     flops_per_s: float
     bytes_per_s: float
-    note: str = ""
+    source: str
+    #: chip-to-chip interconnect, all links together (0: none)
+    ici_bytes_per_s: float = 0.0
 
 
-#: Per-backend peak constants. The TPU row is the v5e pair shared with
-#: ``repro.launch.mesh`` (PEAK_BF16_FLOPS / HBM_BW); cpu/gpu rows are
-#: order-of-magnitude reference points (CI-class 2-core host, A100-40G)
-#: — the roofline terms are for *comparing programs and stages*, not
-#: for predicting absolute wall time on this machine.
-PEAKS: Dict[str, BackendPeaks] = {
-    "tpu": BackendPeaks(197e12, 819e9, "v5e (launch.mesh constants)"),
-    "gpu": BackendPeaks(312e12, 1555e9, "A100-40G bf16"),
-    "cpu": BackendPeaks(1e11, 5e10, "CI-class 2-core host, rough"),
+#: Peaks per ``jax.Device.device_kind``: the one table of the repo.
+#: ``"cpu"`` is an order-of-magnitude reference for comparing programs
+#: and stages on a CI host, not a device measurement.
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        197e12, 819e9, ici_bytes_per_s=200e9,
+        source='Google Cloud documentation, "TPU v5e": per chip 197 '
+               "TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI"),
+    "cpu": DevicePeaks(1e11, 5e10,
+                       source="rough reference for a CI-class host"),
 }
 
 
-def backend_peaks(backend: Optional[str] = None) -> BackendPeaks:
-    """Peak constants for ``backend`` (default: the current jax
-    backend); unknown backends fall back to the cpu row."""
-    b = backend or jax.default_backend()
-    return PEAKS.get(b, PEAKS["cpu"])
-
-
-def _normalize_cost_analysis(ca) -> dict:
-    """jaxlib has returned ``cost_analysis()`` as a dict, a 1-element
-    list of dicts, or None across versions; normalize to one dict."""
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+def device_peaks(kind: Optional[str] = None) -> DevicePeaks:
+    """Peaks of ``kind`` (default: the first device's ``device_kind``).
+    A kind that is not in ``PEAKS`` is an error, not a default."""
+    kind = kind or jax.devices()[0].device_kind
+    if kind not in PEAKS:
+        raise KeyError(f"no peak rates for device kind {kind!r}; add it to "
+                       f"repro.obs.prof.PEAKS with its source "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[kind]
 
 
 @dataclasses.dataclass
@@ -137,10 +135,10 @@ class CostProfile:
 
     @classmethod
     def from_compiled(cls, compiled, name: str,
-                      peaks: Optional[BackendPeaks] = None) -> "CostProfile":
+                      peaks: Optional[DevicePeaks] = None) -> "CostProfile":
         """Build from an already-compiled ``jax.stages.Compiled``."""
-        peaks = peaks or backend_peaks()
-        ca = _normalize_cost_analysis(compiled.cost_analysis())
+        peaks = peaks or device_peaks()
+        ca = compiled.cost_analysis()
         ma = compiled.memory_analysis()
         return cls(
             name=name,
@@ -155,7 +153,7 @@ class CostProfile:
 
 
 def profile_fn(fn: Callable, *args, name: Optional[str] = None,
-               peaks: Optional[BackendPeaks] = None,
+               peaks: Optional[DevicePeaks] = None,
                static_argnums=(), **jit_kwargs) -> CostProfile:
     """Lower + compile ``fn(*args)`` and wrap its compiled cost and
     memory analyses into a :class:`CostProfile`. Nothing executes —
@@ -308,7 +306,7 @@ def _tabular_stage_fns(agent):
 
 def stage_costs(agent, reps: int = 5,
                 spans: Optional[SpanRecorder] = None,
-                peaks: Optional[BackendPeaks] = None) -> dict:
+                peaks: Optional[DevicePeaks] = None) -> dict:
     """Fractional compiled-cost breakdown of a fleet agent's RL loop.
 
     Compiles each stage of the agent's per-step program separately

@@ -101,18 +101,14 @@ def run_manifest(config: Any = None, mesh=None, **extra) -> dict:
     """The provenance stamp. ``mesh`` is a ``jax.sharding.Mesh`` (or
     None); ``extra`` keys (e.g. ``wall_seconds=...``) merge in last."""
     import jax
-    try:
-        import jaxlib
-        jaxlib_version = getattr(jaxlib, "__version__", None)
-    except ImportError:
-        jaxlib_version = None
+    import jaxlib
     m = {
         "schema": MANIFEST_SCHEMA,
         "created_utc": datetime.now(timezone.utc).isoformat(
             timespec="seconds"),
         "git": git_info(),
         "jax_version": jax.__version__,
-        "jaxlib_version": jaxlib_version,
+        "jaxlib_version": jaxlib.__version__,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "device_kinds": sorted({d.device_kind for d in jax.devices()}),

@@ -18,10 +18,13 @@ Robustness semantics (all counted, all conserved):
 * **bounded queues** — a full per-(tier, variant) queue sheds instead
   of growing without bound (``shed_overflow``);
 * **per-queue timeout + retry-once reroute** — an engine call that
-  exceeds ``engine_timeout_s`` abandons the batch; each affected
-  request is rerouted ONCE to the tier's fallback queue (deadline
-  permitting) and otherwise shed (``shed_timeout``). A failed tier
-  degrades gracefully instead of stalling the drain loop;
+  exceeds ``engine_timeout_s`` or raises abandons the batch; each
+  affected request is rerouted ONCE to the tier's fallback queue
+  (deadline permitting) and otherwise shed (``shed_timeout``). A failed
+  tier degrades gracefully instead of stalling the drain loop, and
+  every exception an engine raised is itemized with its type and
+  message in ``stats()["engine_errors"]``, so a compile or out-of-memory
+  failure is never read as a plain timeout;
 * **drain timeout** — ``drain()`` bounds total wait; leftovers are
   shed (``shed_drain``) so the loop always completes.
 
@@ -93,6 +96,7 @@ class ServingBridge:
         self.results: List[Tuple[Request, str, str]] = []
         self.batch_log: List[dict] = []
         self.shed_requests: List[dict] = []
+        self.engine_errors: List[dict] = []
         self.submitted = self.admitted = self.served = 0
         self.rerouted = self.timeouts = 0
         self.shed = {"overflow": 0, "deadline": 0, "timeout": 0,
@@ -222,8 +226,13 @@ class ServingBridge:
             except _FutureTimeout:
                 self._on_timeout(breqs, tier, variant)
                 continue
-            except Exception:
-                # engine failure == timeout for routing purposes
+            except Exception as e:
+                # rerouted like a timeout, but counted as what it was
+                with self._cv:
+                    self.engine_errors.append({
+                        "tier": tier, "variant": variant,
+                        "requests": len(breqs), "type": type(e).__name__,
+                        "message": str(e)})
                 self._on_timeout(breqs, tier, variant)
                 continue
             wall = time.perf_counter() - t_form
@@ -321,7 +330,8 @@ class ServingBridge:
     def stats(self) -> dict:
         """Conserved counters + per-shed detail. ``submitted ==
         admitted + shed(overflow) + shed(deadline)`` and ``served +
-        shed(total) == submitted`` after a clean drain."""
+        shed(total) == submitted`` after a clean drain.
+        ``engine_errors`` lists every exception an engine raised."""
         shed = dict(self.shed)
         shed["total"] = sum(shed.values())
         return {
@@ -332,6 +342,7 @@ class ServingBridge:
             "timeouts": self.timeouts,
             "shed": shed,
             "shed_requests": list(self.shed_requests),
+            "engine_errors": list(self.engine_errors),
             "max_batch": self.cfg.max_batch,
             "max_wait_ms": self.cfg.max_wait_ms,
             "max_queue": self.cfg.max_queue,

@@ -193,3 +193,45 @@ def test_route_bridge_reuse_per_call_accounting():
     st = r2.bridge
     assert st["submitted"] == 2 * n_active
     assert st["served"] + st["shed"]["total"] == st["submitted"]
+
+
+class FailingEngine(StubEngine):
+    """An engine whose every call raises, as a compile or out-of-memory
+    failure on the device would."""
+
+    def serve_batch(self, reqs, toks, spans=None, t_drain=None):
+        self.calls += 1
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+
+def test_bridge_counts_engine_exceptions():
+    """An engine that raises is rerouted like a timeout, but the
+    exception is counted in stats() with its type and message, and
+    route(bridge=...) surfaces it in RouteResult.summary()."""
+    from types import SimpleNamespace
+
+    import jax
+
+    from repro.fleet import FleetConfig, init_fleet
+    from repro.fleet.api import FleetOrchestrator, StaticPolicy
+
+    scen = init_fleet(jax.random.PRNGKey(0),
+                      FleetConfig(cells=2, users=3, arrival_rate=None))
+    n_active = int(np.asarray(scen.active).sum())
+    bad, good = FailingEngine(), StubEngine()
+    bad.model = SimpleNamespace(cfg=SimpleNamespace(vocab_size=32))
+    engines = {"S": {"d0": bad}, "E": {"d0": good}}
+    res = FleetOrchestrator(StaticPolicy(3, "device")).route(
+        scen=scen, dispatch=engines, bridge=BridgeConfig(max_batch=8),
+        max_new_tokens=1, batch_size=8)
+    st = res.summary()["bridge"]
+    assert st["engine_errors"], "the raising engine went uncounted"
+    err = st["engine_errors"][0]
+    assert (err["tier"], err["variant"], err["type"]) == ("S", "d0",
+                                                          "RuntimeError")
+    assert "out of device memory" in err["message"]
+    assert sum(e["requests"] for e in st["engine_errors"]) == n_active
+    # still rerouted: every request was served by the fallback tier
+    assert st["rerouted"] == n_active and st["served"] == n_active
+    assert {r.tier for r in res.served} == {"E"}
+    _assert_conserved(st)

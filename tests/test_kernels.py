@@ -285,6 +285,27 @@ def test_dqn_head_masked_rows_parity(threshold):
                                atol=1e-5, rtol=1e-5)
 
 
+def test_dqn_head_block_cells_fits_vmem_budget():
+    """The fused head's block size comes from a scoped-VMEM budget: a
+    multiple of 8, shrinking as the head widens or top-k planes grow,
+    never below one sublane tile."""
+    from repro.kernels.dqn_head import VMEM_BUDGET, block_cells
+    bc = block_cells(5, 128, 10, 5)
+    assert bc % 8 == 0 and 8 <= bc <= 512
+    assert block_cells(5, 128, 10, 0) >= bc           # no top-k planes
+    assert block_cells(5, 512, 10, 5) <= bc           # wider hidden
+    assert block_cells(5, 128, 10, 5, budget=VMEM_BUDGET // 4) < bc
+    assert block_cells(5, 4096, 10, 5, budget=1) == 8
+
+
+def test_dqn_head_parts_name_the_split():
+    assert ops.dqn_head_parts("pallas", 85.0) == {"head": "pallas",
+                                                  "combo_pick": "xla"}
+    assert ops.dqn_head_parts("pallas", 0.0) == {"head": "pallas"}
+    assert ops.dqn_head_parts("ref", 85.0) == {"head": "ref",
+                                               "combo_pick": "ref"}
+
+
 def test_dqn_head_infeasible_falls_back_to_plain_argmax():
     act, mem, end_b, agg, acc_table = _dqn_case(17, 2, seed=5)
     params = _dqn_params(2, seed=5)

@@ -26,7 +26,7 @@ sys.path.insert(0, ROOT)  # for the benchmarks package
 from repro.fleet import (FleetConfig, FleetDQN, FleetDQNConfig,
                          FleetQConfig, FleetQLearning, SyntheticSource)
 from repro.obs import SpanRecorder, attach_manifest
-from repro.obs.prof import (PEAKS, backend_peaks, profile_fn,
+from repro.obs.prof import (PEAKS, device_peaks, profile_fn,
                             scaling_sweep, stage_costs)
 from repro.obs.report import flatten, rel_diff
 
@@ -72,10 +72,16 @@ def test_costprofile_deterministic_across_recompiles():
 
 
 def test_backend_peaks_known_rows_and_fallback():
-    assert backend_peaks("tpu").flops_per_s == pytest.approx(197e12)
-    assert backend_peaks("no_such_backend") == PEAKS["cpu"]
-    # default resolves to the live backend without raising
-    assert backend_peaks().flops_per_s > 0
+    """Peaks are keyed by device_kind, cite their source, and an unknown
+    kind raises instead of falling back to another row."""
+    v5e = device_peaks("TPU v5 lite")       # what a v5e reports
+    assert v5e.flops_per_s == pytest.approx(197e12)
+    assert v5e.bytes_per_s == pytest.approx(819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no_such_device"):
+        device_peaks("no_such_device")
+    # default resolves the live device's kind without raising
+    assert device_peaks() is PEAKS[jax.devices()[0].device_kind]
 
 
 def test_profile_fn_never_executes():
