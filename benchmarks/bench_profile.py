@@ -4,11 +4,13 @@ via ``repro.obs.prof``.
 
 Measurements:
 
-* ``profile_dqn_stage_*`` / ``profile_tabular_stage_*`` — each RL-loop
-  stage's compiled flops fraction and measured wall fraction
-  (``obs.prof.stage_costs``: stages compiled separately, wall recorded
-  through ``SpanRecorder`` spans). The dominant stage is the fusion
-  the ROADMAP's "Pallas-fused RL hot path" item should write.
+* ``profile_dqn_stage_*`` — each ``FleetDQN`` loop stage's compiled
+  flops fraction and measured wall fraction (``obs.prof.stage_costs``:
+  stages compiled separately, wall recorded through ``SpanRecorder``
+  spans). The dominant stage is the fusion the ROADMAP's "Pallas-fused
+  RL hot path" item should write. ``FleetQLearning``'s stages are the
+  ``fleet.*`` device scopes of its scan, read from a profiler capture
+  (docs/OBSERVABILITY.md), so it has no block here.
 * ``profile_sweep_single`` / ``profile_sweep_sharded`` — the cells-grid
   scaling sweep (``obs.prof.scaling_sweep``): compiled flops/cell vs
   measured device-time/cell, single-device and on the forced
@@ -30,7 +32,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 
 from benchmarks.common import FAST, emit, save_json
-from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning, shard)
+from repro.fleet import FleetConfig, shard
 from repro.fleet.api import SyntheticSource
 from repro.fleet.policy import FleetDQN, FleetDQNConfig
 from repro.obs import SpanRecorder
@@ -69,13 +71,6 @@ def _run(tiny: bool) -> dict:
     dqn_rep = stage_costs(dqn, reps=reps, spans=spans)
     _emit_stages("dqn", dqn_rep)
 
-    tab = FleetQLearning(
-        SyntheticSource(FleetConfig(cells=cells, users=USERS,
-                                    arrival_rate=1.0)),
-        cfg=FleetQConfig(eps_decay=0.0))
-    tab_rep = stage_costs(tab, reps=reps, spans=spans)
-    _emit_stages("tabular", tab_rep)
-
     # scaling sweeps: same grid shape as bench_fleet_sharded so the
     # cliff diagnosis localizes the same flatness number
     grid = [ndev * base, ndev * 4 * base, ndev * 16 * base]
@@ -99,11 +94,9 @@ def _run(tiny: bool) -> dict:
         "devices": ndev,
         "rl_stage_fracs": dqn_rep["flop_fracs"],
         "rl_stage_wall_fracs": dqn_rep["wall_fracs"],
-        "tabular_stage_fracs": tab_rep["flop_fracs"],
         "dominant_stage_flops": dqn_rep["dominant_stage_flops"],
         "dominant_stage_wall": dqn_rep["dominant_stage_wall"],
         "dqn_stages": dqn_rep,
-        "tabular_stages": tab_rep,
         # per-cell compiled cost of one env step at the largest size
         "env_flops_per_cell": single["flops_per_cell"][str(grid[-1])],
         "sweep_single": single,

@@ -40,6 +40,7 @@ from repro.fleet.scenarios import FleetConfig, FleetScenario
 from repro.kernels import ops
 from repro.kernels.ref import first_argmax_ref
 from repro.obs.metrics import MetricDef, MetricsAccumulator
+from repro.obs.spans import span
 
 
 def fleet_metrics(cells: int, kind: str = "tabular", n_windows: int = 0,
@@ -250,7 +251,7 @@ class FleetQLearning:
                  actions: Optional[np.ndarray] = None, seed: int = 0,
                  reset_key=None, mesh=None, metrics: bool = True,
                  n_windows: int = 0, window_len: int = 1,
-                 impl: str = "pallas"):
+                 impl: str = "pallas", spans=None):
         """``scen`` is a ``repro.fleet.api.ScenarioSource`` (reset with
         ``reset_key``, default ``PRNGKey(seed)``) — or, equivalently, a
         ``FleetScenario`` plus its ``FleetConfig`` (wrapped into a
@@ -280,8 +281,17 @@ class FleetQLearning:
         the legacy unfused step (separate gather/argmax/scatter HLOs),
         kept as the reference and the ``rl_unfused_*`` benchmark
         baseline. ``"pallas_interpret"`` forces the real kernel in
-        interpret mode (parity tests; far too slow for training)."""
+        interpret mode (parity tests; far too slow for training).
+
+        ``spans`` (a ``repro.obs.spans.SpanRecorder``, default none)
+        records each ``run`` call as a ``fleet.run`` span with a
+        ``fleet.run.fetch`` child around the blocking reads. Both are
+        profiler annotations with or without a recorder, and the fused
+        scan's ops sit under the device scopes ``fleet.prologue`` /
+        ``act`` / ``respond`` / ``scenario`` / ``update`` /
+        ``telemetry`` (docs/OBSERVABILITY.md)."""
         self.cfg = cfg or FleetQConfig()
+        self.spans = spans
         scen, self.source = resolve_source(scen, fleet_cfg, seed, reset_key)
         self.fleet_cfg = getattr(self.source, "cfg", None)
         self.mesh, scen = adopt_mesh(mesh, self.source, scen)
@@ -357,21 +367,27 @@ class FleetQLearning:
         op_kwargs = self._op_kwargs
 
         def core(q, mets, counts, scen, eps, key, s, greedy):
-            k_exp, k_noise, k_scen = jax.random.split(key, 3)
-            a = self._explore(greedy, eps, k_exp)              # (cells,)
-            per_user = pu[a]                                   # (cells, N)
-            mean_ms, acc, counts2 = simulate_responses(k_noise, scen,
-                                                       per_user, cfg.noise)
-            r = dynamics.reward(mean_ms, acc, cfg.accuracy_threshold,
-                                xp=jnp)
-            scen2, _ = advance(k_scen, scen)
-            s2 = self._state_index(counts2, scen2)
-            q, greedy2, td = ops.fused_tabular_update(
-                q, s, a, r, s2, alpha=cfg.alpha, gamma=cfg.gamma,
-                **op_kwargs)
+            with jax.named_scope("fleet.act"):
+                k_exp, k_noise, k_scen = jax.random.split(key, 3)
+                a = self._explore(greedy, eps, k_exp)          # (cells,)
+                per_user = pu[a]                               # (cells, N)
+            with jax.named_scope("fleet.respond"):
+                mean_ms, acc, counts2 = simulate_responses(
+                    k_noise, scen, per_user, cfg.noise)
+                r = dynamics.reward(mean_ms, acc, cfg.accuracy_threshold,
+                                    xp=jnp)
+            with jax.named_scope("fleet.scenario"):
+                scen2, _ = advance(k_scen, scen)
+                s2 = self._state_index(counts2, scen2)
+            with jax.named_scope("fleet.update"):
+                q, greedy2, td = ops.fused_tabular_update(
+                    q, s, a, r, s2, alpha=cfg.alpha, gamma=cfg.gamma,
+                    **op_kwargs)
             if mets is not None:   # trace-time constant, no host sync
-                mets = mets.update({"reward": r, "mean_ms": mean_ms,
-                                    "td_abs": jnp.abs(td), "epsilon": eps})
+                with jax.named_scope("fleet.telemetry"):
+                    mets = mets.update({"reward": r, "mean_ms": mean_ms,
+                                        "td_abs": jnp.abs(td),
+                                        "epsilon": eps})
             info = {"mean_ms": mean_ms, "mean_acc": acc, "reward": r}
             return q, mets, counts2, scen2, greedy2, info
 
@@ -438,16 +454,21 @@ class FleetQLearning:
             def run(q, mets, counts, scen, eps, key, n):
                 def body(carry, _):
                     q, mets, counts, scen, greedy, eps, key = carry
-                    key, k = jax.random.split(key)
-                    s = self._state_index(counts, scen)
+                    with jax.named_scope("fleet.act"):
+                        key, k = jax.random.split(key)
+                        s = self._state_index(counts, scen)
                     q, mets, counts, scen, greedy, info = core(
                         q, mets, counts, scen, eps, k, s, greedy)
-                    eps = jnp.maximum(eps_min, eps * (1.0 - decay))
-                    return ((q, mets, counts, scen, greedy, eps, key),
-                            (info["mean_ms"].mean(),
-                             info["mean_acc"].mean()))
-                s0 = self._state_index(counts, scen)
-                greedy0 = first_argmax_ref(q[jnp.arange(q.shape[0]), s0])
+                    with jax.named_scope("fleet.act"):
+                        eps = jnp.maximum(eps_min, eps * (1.0 - decay))
+                    with jax.named_scope("fleet.telemetry"):
+                        trace = (info["mean_ms"].mean(),
+                                 info["mean_acc"].mean())
+                    return (q, mets, counts, scen, greedy, eps, key), trace
+                with jax.named_scope("fleet.prologue"):
+                    s0 = self._state_index(counts, scen)
+                    greedy0 = first_argmax_ref(
+                        q[jnp.arange(q.shape[0]), s0])
                 carry, (ms, acc) = jax.lax.scan(
                     body, (q, mets, counts, scen, greedy0, eps, key),
                     None, length=n)
@@ -485,13 +506,17 @@ class FleetQLearning:
     def run(self, n: int):
         """Advance every cell by ``n`` steps inside one jitted scan.
         Returns per-step fleet-mean (ms, accuracy) traces of shape (n,)."""
-        self.key, k = jax.random.split(self.key)
-        (self.q, self.metrics, self.counts, self.scen, eps, _), ms, acc = \
-            self._run(self.q, self.metrics, self.counts, self.scen,
-                      self.eps, k, n)
-        self.eps = float(eps)
+        with span(self.spans, "fleet.run", steps=n,
+                  cells=int(self.q.shape[0])):
+            self.key, k = jax.random.split(self.key)
+            (self.q, self.metrics, self.counts, self.scen, eps, _), ms, \
+                acc = self._run(self.q, self.metrics, self.counts,
+                                self.scen, self.eps, k, n)
+            with span(self.spans, "fleet.run.fetch"):
+                self.eps = float(eps)
+                ms, acc = np.asarray(ms), np.asarray(acc)
         self.steps += n
-        return np.asarray(ms), np.asarray(acc)
+        return ms, acc
 
     def metrics_summary(self):
         """Host-side summary of the in-scan telemetry (``None`` when the

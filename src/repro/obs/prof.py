@@ -14,8 +14,8 @@ fleet program gets the treatment:
   peak constants. No execution happens: the numbers come out of the
   compiled executable, so they are deterministic across runs and
   machines with the same compiler.
-* :func:`stage_costs` — compile the fleet RL loop's stages SEPARATELY
-  (encode/act, env step, replay push+sample, TD/DQN update) and report
+* :func:`stage_costs` — compile ``FleetDQN``'s loop stages SEPARATELY
+  (encode/act, env step, replay push+sample, DQN update) and report
   each stage's fraction of the loop's compiled cost next to measured
   wall time (recorded through ``obs.spans.SpanRecorder``). This is the
   map the ROADMAP's "Pallas-fused RL hot path" item needs: the stage
@@ -230,89 +230,14 @@ def _dqn_stage_fns(agent):
     }
 
 
-def _tabular_stage_fns(agent):
-    """(name -> (fn, args)) decomposition of ``FleetQLearning``'s step.
-
-    Legacy (``impl='xla'``) stages: eps-greedy act (state index +
-    gather + argmax), env step, TD scatter-update. Fused agents
-    replace the last with ``fused_update_act`` — the single
-    ``kernels.ops.fused_tabular_update`` call that covers the TD
-    update AND the next step's act-side gather/argmax (the scan
-    carries its ``greedy2``), so ``encode_act`` shrinks to the state
-    index + exploration draw."""
-    from repro.fleet.api import make_env_step
-
-    cfg = agent.cfg
-    env_step = make_env_step(agent.source,
-                             threshold=cfg.accuracy_threshold,
-                             noise=cfg.noise)
-    pu, n_actions = agent.pu_table, agent.n_actions
-    key = jax.random.PRNGKey(0)
-    scen, counts = agent.scen, agent.counts
-    a0 = jnp.zeros((scen.cells,), jnp.int32)
-    r = jnp.zeros((scen.cells,), jnp.float32)
-
-    if getattr(agent, "_op_impl", "xla") != "xla":
-        from repro.kernels import ops
-        s0 = jnp.zeros((scen.cells,), jnp.int32)
-        g0 = jnp.zeros((scen.cells,), jnp.int32)
-
-        def encode_act(counts, scen, greedy, eps, key):
-            s = agent._state_index(counts, scen)
-            a = agent._explore(greedy, eps, key)
-            return s, a, pu[a]
-
-        def fused_update_act(q, s, a, r, s2):
-            return ops.fused_tabular_update(
-                q, s, a, r, s2, alpha=cfg.alpha, gamma=cfg.gamma,
-                **agent._op_kwargs)
-
-        return {
-            "encode_act": (encode_act,
-                           (counts, scen, g0, agent.eps, key)),
-            "env_step": (lambda key, scen, a: env_step(key, scen, a),
-                         (key, scen, jnp.zeros((scen.cells, scen.users),
-                                               jnp.int32))),
-            "fused_update_act": (fused_update_act,
-                                 (agent.q, s0, a0, r, s0)),
-        }
-
-    def encode_act(q, counts, scen, eps, key):
-        cells = jnp.arange(q.shape[0])
-        s = agent._state_index(counts, scen)
-        u = jax.random.uniform(key, (q.shape[0],))
-        rand = jnp.minimum((u / jnp.maximum(eps, 1e-9)
-                            * n_actions).astype(jnp.int32), n_actions - 1)
-        a = jnp.where(u < eps, rand, q[cells, s].argmax(-1))
-        return a, pu[a]
-
-    def td_update(q, counts, scen, a, r, counts2, scen2):
-        cells = jnp.arange(q.shape[0])
-        s = agent._state_index(counts, scen)
-        s2 = agent._state_index(counts2, scen2)
-        td = r + cfg.gamma * q[cells, s2].max(-1) - q[cells, s, a]
-        return q.at[cells, s, a].add(cfg.alpha * td)
-
-    return {
-        "encode_act": (encode_act,
-                       (agent.q, counts, scen, agent.eps, key)),
-        "env_step": (lambda key, scen, a: env_step(key, scen, a),
-                     (key, scen, jnp.zeros((scen.cells, scen.users),
-                                           jnp.int32))),
-        "update": (td_update, (agent.q, counts, scen, a0, r, counts,
-                               scen)),
-    }
-
-
 def stage_costs(agent, reps: int = 5,
                 spans: Optional[SpanRecorder] = None,
                 peaks: Optional[DevicePeaks] = None) -> dict:
-    """Fractional compiled-cost breakdown of a fleet agent's RL loop.
+    """Fractional compiled-cost breakdown of ``FleetDQN``'s RL loop.
 
     Compiles each stage of the agent's per-step program separately
-    (``FleetDQN``: encode/act, env step, replay push+sample, DQN
-    update; ``FleetQLearning``: encode/act, env step, TD update),
-    profiles the compiled cost of each, and measures ``reps`` blocked
+    (encode/act, env step, replay push+sample, DQN update), profiles
+    the compiled cost of each, and measures ``reps`` blocked
     executions per stage through ``SpanRecorder`` spans
     (``prof.stage.{name}`` on ``spans`` when given).
 
@@ -325,12 +250,18 @@ def stage_costs(agent, reps: int = 5,
     cost is an upper bound on the fused scan body (XLA fuses across
     stage boundaries), but the *fractions* are what localize the hot
     stage, and they are deterministic across recompiles.
+
+    ``FleetQLearning`` has no such split: its scan's stages are device
+    scopes (``fleet.act`` ... ``fleet.telemetry``), timed from a
+    profiler capture of the real loop (docs/OBSERVABILITY.md).
     """
-    stage_fns = (_dqn_stage_fns(agent) if hasattr(agent, "buffer")
-                 else _tabular_stage_fns(agent))
-    kind = "dqn" if hasattr(agent, "buffer") else "tabular"
+    if not hasattr(agent, "buffer"):
+        raise TypeError(
+            f"stage_costs profiles FleetDQN; {type(agent).__name__}'s "
+            "stages are the fleet.* device scopes of its scan — read "
+            "them from a profiler capture")
     stages = {}
-    for name, (fn, args) in stage_fns.items():
+    for name, (fn, args) in _dqn_stage_fns(agent).items():
         jfn = jax.jit(fn)
         prof = CostProfile.from_compiled(jfn.lower(*args).compile(),
                                          name, peaks)
@@ -345,7 +276,7 @@ def stage_costs(agent, reps: int = 5,
     flop_fracs = fracs("flops")
     wall_fracs = fracs("wall_ms")
     return {
-        "kind": kind,
+        "kind": "dqn",
         "cells": int(agent.scen.cells),
         "users": int(agent.scen.users),
         "backend": jax.default_backend(),

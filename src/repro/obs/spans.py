@@ -9,9 +9,11 @@ trace-event format, which both ``chrome://tracing`` and
 https://ui.perfetto.dev load directly.
 
 Every instrumentation point in the repo takes an optional
-``spans=None`` argument and calls the module-level :func:`span` helper,
-which is a no-op ``nullcontext`` when the recorder is ``None`` — the
-uninstrumented path stays allocation-free.
+``spans=None`` argument and calls the module-level :func:`span` helper.
+With no recorder it still enters the ``TraceAnnotation`` (name and
+args), so a profiler capture shows the program's spans on the device
+trace's clock; it costs about half a microsecond a span when no capture
+is running, and no Chrome JSON is kept.
 
 Format reference: the Trace Event Format doc (Chromium). We emit
 "X" (complete) events with microsecond ``ts``/``dur`` relative to the
@@ -53,7 +55,7 @@ class SpanRecorder:
     def span(self, name: str, **args):
         """Time a region; nests device work via TraceAnnotation."""
         t_start = self._clock()
-        with jax.profiler.TraceAnnotation(name):
+        with jax.profiler.TraceAnnotation(name, **args):
             try:
                 yield self
             finally:
@@ -132,9 +134,10 @@ class SpanRecorder:
 
 
 def span(recorder: Optional[SpanRecorder], name: str, **args):
-    """None-safe span: a nullcontext when no recorder is attached."""
+    """None-safe span: with no recorder attached, only the profiler's
+    ``TraceAnnotation`` of the same name and args."""
     if recorder is None:
-        return contextlib.nullcontext()
+        return jax.profiler.TraceAnnotation(name, **args)
     return recorder.span(name, **args)
 
 

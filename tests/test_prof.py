@@ -3,9 +3,10 @@ gate.
 
 Covers: CostProfile flops sanity on a known matmul (compiler count
 within 2x of the analytic 2mnk), determinism across recompiles,
-roofline terms and backend-peak fallback, stage_costs for both fleet
-agents (stage sets, fractions summing to ~1, determinism of the flop
-fractions, spans recorded), scaling_sweep report schema + JSON
+roofline terms and backend-peak fallback, stage_costs for FleetDQN
+(stage sets, fractions summing to ~1, determinism of the flop
+fractions, spans recorded; the tabular agent is refused, its stages
+being device scopes), scaling_sweep report schema + JSON
 round-trip, tools/benchgate.py via subprocess (pass / regression /
 manifest mismatch / --force / structural on the tracked baseline and
 on a broken JSON), obsview --fail-on-move and --history, and the
@@ -120,22 +121,14 @@ def test_stage_costs_dqn_stages_and_fractions():
     json.dumps(rep)
 
 
-def test_stage_costs_tabular_stages_and_fractions():
-    agent = FleetQLearning(_source(), cfg=FleetQConfig())
-    rep = stage_costs(agent, reps=2)
-    assert rep["kind"] == "tabular"
-    # default impl: TD update + next-step act fused into one stage
-    assert set(rep["stages"]) == {"encode_act", "env_step",
-                                  "fused_update_act"}
-    assert sum(rep["flop_fracs"].values()) == pytest.approx(1.0)
-    assert rep["cells"] == 8 and rep["users"] == 2
-    json.dumps(rep)
+def test_stage_costs_refuses_tabular_agent():
+    """FleetQLearning's stages are the fleet.* scopes of its scan, timed
+    in a profiler capture; no stand-alone stage programs stand in."""
+    with pytest.raises(TypeError, match="fleet.* device scopes"):
+        stage_costs(FleetQLearning(_source(), cfg=FleetQConfig()))
 
 
 def test_stage_costs_xla_impl_keeps_legacy_stage_names():
-    rep = stage_costs(FleetQLearning(_source(), cfg=FleetQConfig(),
-                                     impl="xla"), reps=1)
-    assert set(rep["stages"]) == {"encode_act", "env_step", "update"}
     rep = stage_costs(FleetDQN(_source(),
                                cfg=FleetDQNConfig(replay_capacity=256,
                                                   batch_size=16),

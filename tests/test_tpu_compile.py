@@ -70,3 +70,31 @@ def test_dqn_head_compiles_for_v5e(one_chip, threshold):
                         _shape(one_chip, (USERS, N_ACT)),
                         _shape(one_chip, (N_ACT,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tabular_scan_keeps_kernel_name_under_update_scope(one_chip):
+    """The fleet scan with the compiled kernel, at a v5e: the kernel's
+    custom call keeps the name the benchmark's roofline matches
+    (``fused_tabular_update.N``) and sits under the ``fleet.update``
+    scope; the greedy gather before the scan is the prologue."""
+    import re
+
+    from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning,
+                             SyntheticSource)
+    agent = FleetQLearning(SyntheticSource(FleetConfig(cells=1024,
+                                                       users=USERS)),
+                           cfg=FleetQConfig())
+    agent._op_kwargs = ops.rl_op_kwargs("pallas")
+    run = jax.jit(agent._make_run(), static_argnums=(6,),
+                  donate_argnums=(0, 1))
+    args = jax.tree.map(
+        lambda x: _shape(one_chip, jnp.shape(x), jnp.result_type(x)),
+        (agent.q, agent.metrics, agent.counts, agent.scen,
+         jnp.float32(agent.eps), agent.key))
+    text = run.lower(*args, 8).compile().as_text()
+    calls = re.findall(r"%(fused_tabular_update(?:\.\d+)?) = .*? "
+                       r"custom-call\(.*op_name=\"([^\"]*)\"", text)
+    assert len(calls) == 1
+    assert "/fleet.update/" in calls[0][1]
+    assert "/while/body/" in calls[0][1]
+    assert re.search(r'op_name="jit\(run\)/fleet\.prologue/gather"', text)
