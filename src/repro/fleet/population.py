@@ -37,7 +37,7 @@ import numpy as np
 from repro.core.spaces import SpaceSpec, restricted_actions
 from repro.fleet import dynamics, topology
 from repro.fleet.scenarios import FleetConfig, FleetScenario
-from repro.kernels import ops
+from repro.kernels import ops, tabular_rl
 from repro.kernels.ref import first_argmax_ref
 from repro.obs.metrics import MetricDef, MetricsAccumulator
 from repro.obs.spans import span
@@ -289,7 +289,7 @@ class FleetQLearning:
         profiler annotations with or without a recorder, and the fused
         scan's ops sit under the device scopes ``fleet.prologue`` /
         ``act`` / ``respond`` / ``scenario`` / ``update`` /
-        ``telemetry`` (docs/OBSERVABILITY.md)."""
+        ``telemetry`` / ``epilogue`` (docs/OBSERVABILITY.md)."""
         self.cfg = cfg or FleetQConfig()
         self.spans = spans
         scen, self.source = resolve_source(scen, fleet_cfg, seed, reset_key)
@@ -356,15 +356,19 @@ class FleetQLearning:
                            n_actions - 1)
         return jnp.where(u < eps, rand, greedy)
 
-    def _make_fused_core(self):
+    def _make_fused_core(self, aligned: bool = False):
         """env step + fused TD update from a precomputed ``(s, greedy)``
         pair — the body shared by the fused single-step and the fused
         scan (which carries ``greedy2`` instead of re-gathering the
         ``s2`` Q-row next step). Splits the key exactly like the legacy
-        step, so fused and unfused trajectories use identical RNG."""
+        step, so fused and unfused trajectories use identical RNG.
+        ``aligned``: ``q`` comes in the kernel's layout
+        (``tabular_rl.align_table``) and stays in it."""
         cfg, pu = self.cfg, self.pu_table
         advance = self.source.step
-        op_kwargs = self._op_kwargs
+        op_kwargs = dict(self._op_kwargs)
+        if aligned:
+            op_kwargs["n_actions"] = self.n_actions
 
         def core(q, mets, counts, scen, eps, key, s, greedy):
             with jax.named_scope("fleet.act"):
@@ -446,10 +450,15 @@ class FleetQLearning:
         call (amortizes dispatch; donation keeps the table in place).
         The fused path carries each step's ``greedy2`` through the scan
         — the act-side Q-row gather+argmax happens once, in the fused
-        update of the PREVIOUS step, instead of once per step."""
+        update of the PREVIOUS step, instead of once per step. On the
+        kernel path the scan carries the table in the kernel's layout:
+        aligned once before it (``fleet.prologue``) and given back
+        logical once after it (``fleet.epilogue``)."""
         decay, eps_min = self.cfg.eps_decay, self.cfg.eps_min
         if self._op_impl != "xla":
-            core = self._make_fused_core()
+            kernel = self._op_kwargs["impl"] == "pallas"
+            core = self._make_fused_core(aligned=kernel)
+            n_states, n_actions = self.n_states, self.n_actions
 
             def run(q, mets, counts, scen, eps, key, n):
                 def body(carry, _):
@@ -467,12 +476,19 @@ class FleetQLearning:
                     return (q, mets, counts, scen, greedy, eps, key), trace
                 with jax.named_scope("fleet.prologue"):
                     s0 = self._state_index(counts, scen)
-                    greedy0 = first_argmax_ref(
-                        q[jnp.arange(q.shape[0]), s0])
+                    if kernel:
+                        q = tabular_rl.align_table(q)
+                        rows = tabular_rl.gather_rows(q, s0, n_actions)
+                    else:
+                        rows = q[jnp.arange(q.shape[0]), s0]
+                    greedy0 = first_argmax_ref(rows)
                 carry, (ms, acc) = jax.lax.scan(
                     body, (q, mets, counts, scen, greedy0, eps, key),
                     None, length=n)
                 q, mets, counts, scen, _, eps, key = carry
+                if kernel:
+                    with jax.named_scope("fleet.epilogue"):
+                        q = tabular_rl.unalign_table(q, n_states, n_actions)
                 return (q, mets, counts, scen, eps, key), ms, acc
 
             return run

@@ -13,13 +13,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import ref, tabular_rl
 from repro.kernels.decode_attention import decode_attention_kernel
 from repro.kernels.dqn_head import block_cells, dqn_head_kernel
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.int8_matmul import int8_matmul_kernel
 from repro.kernels.selective_scan import selective_scan_kernel
-from repro.kernels.tabular_rl import tabular_rl_kernel
 
 NEG_INF = -1e30
 
@@ -187,22 +186,38 @@ def dqn_head_parts(resolved: str, threshold: float) -> dict:
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "gamma", "impl",
-                                             "bc", "interpret"))
+                                             "n_actions", "bc", "interpret"))
 def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
-                         impl: str = "ref", bc: int = 8,
-                         interpret: bool = True):
+                         impl: str = "ref", n_actions: Optional[int] = None,
+                         bc: Optional[int] = None, interpret: bool = True):
     """Fused tabular act+update: q (cells,S,K) f32, s/a/s2 (cells,)
     int32, r (cells,) f32 -> (q_new, greedy2, td); see
-    ``ref.fused_tabular_ref``."""
+    ``ref.fused_tabular_ref``.
+
+    The kernel path takes the table in its own layout
+    (``tabular_rl.align_table``). A caller that keeps it so across many
+    steps, as the fleet scan does, passes that table with
+    ``n_actions``, its logical width, and gets ``q_new`` back in that
+    layout; a logical table (``n_actions`` None) is aligned for the call
+    and given back logical. ``bc`` defaults to the largest block that
+    fits the kernel's VMEM budget (``tabular_rl.block_cells``)."""
     if impl == "ref":
+        if n_actions is not None:
+            raise ValueError("the ref path takes a logical table")
         return ref.fused_tabular_ref(q, s, a, r, s2, alpha=alpha,
                                      gamma=gamma)
-    cells = q.shape[0]
-    q_, _ = _pad_to(q, 0, bc)
-    cols = [_pad_to(x, 0, bc)[0] for x in (s, a, r, s2)]
-    q_new, greedy2, td = tabular_rl_kernel(
-        q_, *cols, alpha=alpha, gamma=gamma, bc=bc, interpret=interpret)
-    return q_new[:cells], greedy2[:cells, 0], td[:cells, 0]
+    cells, n_states, width = q.shape
+    logical = n_actions is None
+    n_actions = width if logical else n_actions
+    if bc is None:
+        bc = tabular_rl.block_cells(n_actions)
+    cols = [_pad_to(x, 0, bc)[0].reshape(-1, 1, bc) for x in (s, a, r, s2)]
+    q_new, greedy2, td = tabular_rl.tabular_rl_kernel(
+        tabular_rl.align_table(q) if logical else q, *cols, alpha=alpha,
+        gamma=gamma, n_actions=n_actions, bc=bc, interpret=interpret)
+    if logical:
+        q_new = tabular_rl.unalign_table(q_new, n_states, width)
+    return q_new, greedy2.reshape(-1)[:cells], td.reshape(-1)[:cells]
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "topk", "impl",

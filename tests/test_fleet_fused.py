@@ -62,16 +62,24 @@ def test_tabular_stepwise_bit_identical_across_impls():
 
 
 def test_tabular_interpret_kernel_training_matches_xla():
-    """The real Pallas kernel (interpret mode, tiny fleet): identical
-    trajectories up to the kernel's fma-contraction ulp."""
-    a = _tabular("xla", cells=4)
-    b = _tabular("pallas_interpret", cells=4)
-    a.run(12)
-    b.run(12)
-    np.testing.assert_allclose(np.asarray(a.q), np.asarray(b.q),
-                               atol=1e-5, rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(a.counts),
-                                  np.asarray(b.counts))
+    """The real Pallas kernel (interpret mode) on a ragged fleet (13
+    cells, not a block's multiple): two scanned ``run`` calls and one
+    ``step``, identical trajectories up to the kernel's fma-contraction
+    ulp. The scan carries the table in the kernel's layout, and
+    ``agent.q`` keeps its logical shape between calls."""
+    a = _tabular("xla", cells=13)
+    b = _tabular("pallas_interpret", cells=13)
+    shape = (13, b.n_states, b.n_actions)
+    assert b.q.shape == shape
+    for advance in (lambda ag: ag.run(6), lambda ag: ag.run(6),
+                    lambda ag: ag.step()):
+        advance(a)
+        advance(b)
+        assert b.q.shape == shape
+        np.testing.assert_allclose(np.asarray(a.q), np.asarray(b.q),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(np.asarray(a.counts),
+                                      np.asarray(b.counts))
 
 
 def test_tabular_unknown_impl_raises():

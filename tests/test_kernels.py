@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, tabular_rl
 
 KEY = jax.random.PRNGKey(0)
 
@@ -199,6 +199,78 @@ def test_tabular_kernel_tie_break_first_index():
         q, s, a, r, s2, alpha=ALPHA, gamma=GAMMA, impl="pallas", bc=8,
         interpret=True)
     np.testing.assert_array_equal(np.asarray(want_g), np.asarray(got_g))
+
+
+@pytest.mark.parametrize("cells,states,k,bc,aligned", [
+    (45, 9, 10, 16, False),       # ragged: 45 cells in blocks of 16
+    (24, 13, 130, 8, False),      # S not a multiple of 8, K not of 128
+    (21, 13, 130, 8, True),       # pre-aligned, as the fleet scan passes it
+    (19, 36, 243, None, True),    # the benchmark's rows, default block
+])
+def test_tabular_kernel_layouts(cells, states, k, bc, aligned):
+    """The kernel against the oracle on the shapes its layout has to
+    absorb. A pre-aligned table comes with a huge value in every padded
+    row and lane: none may enter a max or an argmax, and each must come
+    back as it went in. Greedy and the untouched entries bit-exact, the
+    touched ones within the parity test's tolerance."""
+    q, s, a, r, s2 = _tabular_case(cells, states=states, k=k, seed=k)
+    want_q, want_g, want_td = ref.fused_tabular_ref(
+        q, s, a, r, s2, alpha=ALPHA, gamma=GAMMA)
+    kw = dict(alpha=ALPHA, gamma=GAMMA, impl="pallas", bc=bc,
+              interpret=True)
+    if aligned:
+        pad = np.asarray(tabular_rl.align_table(jnp.ones_like(q))) == 0
+        qa = jnp.where(pad, 1e30, tabular_rl.align_table(q))
+        got_qa, got_g, got_td = ops.fused_tabular_update(
+            qa, s, a, r, s2, n_actions=k, **kw)
+        assert got_qa.shape == qa.shape
+        np.testing.assert_array_equal(np.asarray(got_qa)[pad],
+                                      np.asarray(qa)[pad])
+        got_q = tabular_rl.unalign_table(got_qa, states, k)
+    else:
+        got_q, got_g, got_td = ops.fused_tabular_update(q, s, a, r, s2, **kw)
+    assert got_q.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(want_g), np.asarray(got_g))
+    touched = np.zeros(q.shape, bool)
+    touched[np.arange(cells), np.asarray(s), np.asarray(a)] = True
+    np.testing.assert_array_equal(np.asarray(got_q)[~touched],
+                                  np.asarray(q)[~touched])
+    np.testing.assert_allclose(np.asarray(got_q), np.asarray(want_q),
+                               atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_td), np.asarray(want_td),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("piece_cells", [3, 11])
+def test_tabular_layout_round_trip(monkeypatch, piece_cells):
+    """``align_table``/``unalign_table`` are exact inverses, whole or a
+    piece of cells at a time (11 cells in pieces of 3: the last piece
+    overlaps the one before), and ``gather_rows`` reads the logical
+    rows from the kernel's layout."""
+    q, s, _, _, _ = _tabular_case(11, states=13, k=130, seed=4)
+    whole = tabular_rl.align_table(q)
+    monkeypatch.setattr(tabular_rl, "PIECE_BYTES",
+                        piece_cells * q[0].size * 4)
+    qa = tabular_rl.align_table(q)
+    assert qa.shape == (11, 16 * 2, 128)
+    np.testing.assert_array_equal(np.asarray(qa), np.asarray(whole))
+    np.testing.assert_array_equal(
+        np.asarray(tabular_rl.unalign_table(qa, 13, 130)), np.asarray(q))
+    np.testing.assert_array_equal(
+        np.asarray(tabular_rl.gather_rows(qa, s, 130)),
+        np.asarray(q[jnp.arange(11), s]))
+
+
+def test_tabular_block_cells_ignores_states():
+    """Two groups, two slots and six index columns a cell in 12 MiB: 256
+    cells at 243 actions (a multiple of 128, for the column transposes),
+    the 512 cap at 10, a multiple of 8 where fewer than 128 fit, and
+    nothing depends on the number of states."""
+    assert tabular_rl.block_cells(243) == 256
+    assert tabular_rl.block_cells(10) == 512
+    assert tabular_rl.block_cells(243, budget=2 ** 20) == 24
+    per_cell = 4 * (2 * 2 * 8 * 256 + 6 * 128)
+    assert 256 * per_cell <= tabular_rl.VMEM_BUDGET < 384 * per_cell
 
 
 def test_resolve_rl_impl_gating():

@@ -20,6 +20,7 @@ import pytest
 from repro.kernels import ops
 
 TAB_CELLS, STATES, ACTIONS = 65536, 36, 243   # the smoke's 2.29 GB Q-table
+BENCH_CELLS = 131072                          # the benchmark's 4.59 GB table
 DQN_CELLS, USERS, HIDDEN, TOPK, N_ACT = 16384, 5, 128, 5, 10
 
 
@@ -51,6 +52,18 @@ def test_tabular_kernel_compiles_for_v5e(one_chip):
     idx = _shape(one_chip, (TAB_CELLS,), jnp.int32)
     compiled = fn.lower(_shape(one_chip, (TAB_CELLS, STATES, ACTIONS)), idx,
                         idx, _shape(one_chip, (TAB_CELLS,)), idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tabular_kernel_small_ragged_block_compiles_for_v5e(one_chip):
+    """The parity tests' shapes: an 8-cell block (narrower than a lane
+    tile), a ragged last block, 9 states and 10 actions."""
+    fn = jax.jit(lambda q, s, a, r, s2: ops.fused_tabular_update(
+        q, s, a, r, s2, alpha=0.9, gamma=0.1, impl="pallas", bc=8,
+        interpret=False), donate_argnums=0)
+    idx = _shape(one_chip, (37,), jnp.int32)
+    compiled = fn.lower(_shape(one_chip, (37, 9, 10)), idx, idx,
+                        _shape(one_chip, (37,)), idx).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -98,3 +111,48 @@ def test_tabular_scan_keeps_kernel_name_under_update_scope(one_chip):
     assert "/fleet.update/" in calls[0][1]
     assert "/while/body/" in calls[0][1]
     assert re.search(r'op_name="jit\(run\)/fleet\.prologue/gather"', text)
+
+
+def _scan(one_chip, cells, **cfg):
+    """The fleet scan with the compiled kernel, compiled for a v5e at
+    ``cells`` cells. The agent is built at 3 cells, so that no
+    full-size table is ever made here; its per-cell state is described
+    at ``cells``, and its telemetry is made at ``cells`` (small)."""
+    from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning,
+                             SyntheticSource)
+    from repro.fleet.population import fleet_metrics
+    agent = FleetQLearning(SyntheticSource(FleetConfig(cells=3,
+                                                       users=USERS)),
+                           cfg=FleetQConfig(**cfg))
+    agent.metrics = fleet_metrics(cells, "tabular")
+    agent._op_kwargs = ops.rl_op_kwargs("pallas")
+    run = jax.jit(agent._make_run(), static_argnums=(6,),
+                  donate_argnums=(0, 1))
+    args = jax.tree.map(
+        lambda x: _shape(one_chip, (cells,) + jnp.shape(x)[1:]
+                         if jnp.shape(x)[:1] == (3,) else jnp.shape(x),
+                         jnp.result_type(x)),
+        (agent.q, agent.metrics, agent.counts, agent.scen,
+         jnp.float32(agent.eps), agent.key))
+    return agent, run.lower(*args, 8).compile()
+
+
+def test_tabular_scan_at_benchmark_size_holds_one_padded_table(one_chip):
+    """At the benchmark's 131,072 x 36 x 243 the scan carries the table
+    in the kernel's layout, padded to 40 x 256: its temporaries are that
+    one table and at most 10% more (the pieces of the layout changes
+    around the scan, the step's own arrays)."""
+    agent, compiled = _scan(one_chip, BENCH_CELLS)
+    assert (agent.n_states, agent.n_actions) == (STATES, ACTIONS)
+    padded = BENCH_CELLS * 40 * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * padded
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tabular_scan_compiles_with_link_states(one_chip):
+    """``track_links=True``: 2,304 states a cell at 2,048 cells (4.59
+    GB). The kernel's VMEM does not grow with the states, so it
+    compiles."""
+    agent, compiled = _scan(one_chip, 2048, track_links=True)
+    assert agent.n_states == 2304
+    assert "tpu_custom_call" in compiled.as_text()
