@@ -309,8 +309,11 @@ class FleetQLearning:
         self._count_states = (users + 1) ** 2
         self._link_states = 2 ** (users + 1) if self.cfg.track_links else 1
         self.n_states = self._count_states * self._link_states
-        self.q = jnp.zeros((scen.cells, self.n_states, self.n_actions),
-                           jnp.float32)
+        from repro.fleet import shard
+        # under a mesh each device makes its own block: whole, the table
+        # of a fleet sized for the mesh does not fit one device
+        self.q = shard.zeros((scen.cells, self.n_states, self.n_actions),
+                             jnp.float32, self.mesh)
         self.scen = scen
         self.counts = jnp.zeros((scen.cells, 2), jnp.int32)
         self.metrics = fleet_metrics(scen.cells, "tabular",
@@ -318,8 +321,6 @@ class FleetQLearning:
                                      window_len=window_len) if metrics \
             else None
         if self.mesh is not None:
-            from repro.fleet import shard
-            self.q = shard.shard_array(self.q, self.mesh)
             self.counts = shard.shard_array(self.counts, self.mesh)
             self.metrics = place_metrics(self.metrics, self.mesh)
         self.eps = self.cfg.eps_start

@@ -75,7 +75,7 @@ from repro.fleet.scenarios import FleetScenario
 from repro.fleet.topology import Topology, shard_blocks
 
 __all__ = [
-    "FLEET_AXIS", "fleet_mesh", "fleet_spec", "shard_array",
+    "FLEET_AXIS", "fleet_mesh", "fleet_spec", "shard_array", "zeros",
     "constrain_array", "replicate", "shard_topology", "shard_scenario",
     "constrain_scenario", "shard_replay", "local_contention",
     "local_expected_response", "check_shard_local",
@@ -127,6 +127,18 @@ def constrain_array(x, mesh: Optional[Mesh], axis: int = 0,
         x, NamedSharding(mesh, fleet_spec(mesh, x.shape, axis, logical)))
 
 
+def zeros(shape, dtype, mesh: Optional[Mesh], axis: int = 0,
+          logical: str = "cells"):
+    """Zeros of ``shape`` placed as ``shard_array`` places them, made by
+    each device for its own block: no device ever holds the whole array,
+    which for a fleet's Q-table may be larger than one device's memory
+    (plain ``jnp.zeros`` when ``mesh`` is None)."""
+    if mesh is None:
+        return jnp.zeros(shape, dtype)
+    out = NamedSharding(mesh, fleet_spec(mesh, shape, axis, logical))
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=out)()
+
+
 def replicate(tree, mesh: Optional[Mesh]):
     """Replicate every leaf of ``tree`` across the mesh (the placement
     for DQN params / optimizer state; identity when ``mesh`` is None)."""
@@ -169,10 +181,13 @@ def _map_scenario(s: FleetScenario, mesh: Optional[Mesh], place,
                   place_topo, place_rep) -> FleetScenario:
     if mesh is None:
         return s
-    # calib is tier-indexed (3,) metadata, not per-cell: replicate it
+    # the step counter and calib (tier-indexed (3,) metadata) are not
+    # per-cell: replicate them. A ``t`` left uncommitted would come back
+    # from a jitted step replicated, and the next call would compile anew
     return FleetScenario(
         place(s.end_b, mesh), place(s.edge_b, mesh), place(s.member, mesh),
-        place(s.active, mesh), s.t, place_topo(s.topo, mesh),
+        place(s.active, mesh), place_rep(s.t, mesh),
+        place_topo(s.topo, mesh),
         None if s.calib is None else place_rep(s.calib, mesh))
 
 

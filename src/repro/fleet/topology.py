@@ -251,20 +251,25 @@ def shared_contention(per_user, topo: Topology, active=None, xp=jnp):
     ``dynamics.response_times``. Under ``identity_topology`` the
     effective counts equal the isolated per-cell counts bit-exactly and
     the multiplier is exactly 1.0.
+
+    Traced, its ops (and, under a fleet mesh, the cross-device
+    reductions the partitioner puts around the sums) sit under the
+    device scope ``fleet.contention`` (docs/OBSERVABILITY.md).
     """
-    per_user = xp.asarray(per_user)
-    at_edge = per_user == dynamics.A_EDGE
-    at_cloud = per_user == dynamics.A_CLOUD
-    if active is not None:
-        active = xp.asarray(active)
-        at_edge = at_edge & active
-        at_cloud = at_cloud & active
-    e_cnt = at_edge.sum(-1)
-    c_cnt = at_cloud.sum(-1)
-    edge_tot = _segment_totals(e_cnt, topo.cell_edge, topo.n_edges, xp)
-    cap = xp.asarray(topo.edge_capacity)
-    n_e_eff = edge_tot[topo.cell_edge] / cap[topo.cell_edge]
-    mult = cloud_load_multiplier(c_cnt.sum(), topo.cloud_servers, xp=xp)
+    with jax.named_scope("fleet.contention"):
+        per_user = xp.asarray(per_user)
+        at_edge = per_user == dynamics.A_EDGE
+        at_cloud = per_user == dynamics.A_CLOUD
+        if active is not None:
+            active = xp.asarray(active)
+            at_edge = at_edge & active
+            at_cloud = at_cloud & active
+        e_cnt = at_edge.sum(-1)
+        c_cnt = at_cloud.sum(-1)
+        edge_tot = _segment_totals(e_cnt, topo.cell_edge, topo.n_edges, xp)
+        cap = xp.asarray(topo.edge_capacity)
+        n_e_eff = edge_tot[topo.cell_edge] / cap[topo.cell_edge]
+        mult = cloud_load_multiplier(c_cnt.sum(), topo.cloud_servers, xp=xp)
     return n_e_eff, c_cnt, mult
 
 

@@ -141,3 +141,21 @@ def test_span_without_recorder_enters_a_trace_annotation(tmp_path):
     assert set(got) == {"outer.none", "inner.none"}
     assert got["outer.none"][3] == {"k": 7}
     assert got["outer.none"][1] <= got["inner.none"][1]
+
+
+@pytest.mark.parametrize("n_edges", [None, 16])
+def test_contention_scope_only_where_cells_share_edges(n_edges):
+    """With a topology the shared-edge sums sit under ``fleet.contention``
+    inside ``fleet.respond``; isolated cells have no such ops."""
+    src = SyntheticSource(FleetConfig(cells=64, users=2, p_r2w=0.05,
+                                      p_w2r=0.05, n_edges=n_edges))
+    ag = FleetQLearning(src, cfg=FleetQConfig(), seed=3, impl="ref")
+    text = ag._run.lower(ag.q, ag.metrics, ag.counts, ag.scen, ag.eps,
+                         jax.random.PRNGKey(0), 5).compile().as_text()
+    names = [_op_name(ln) for ln in text.splitlines()
+             if "fleet.contention" in _op_name(ln)]
+    if n_edges is None:
+        assert names == []
+    else:
+        assert any("segment_sum" in n or "scatter-add" in n for n in names)
+        assert all("fleet.respond/fleet.contention/" in n for n in names)

@@ -175,6 +175,46 @@ def test_fused_impl_sharded_training_bit_parity():
         assert meshed.q.sharding.spec[0] == "fleet"
 
 
+def test_zeros_are_made_on_each_device():
+    """``shard.zeros`` makes each device's block where it lives, with no
+    transfer: the agent's Q-table under a mesh is made so, and is never
+    whole on one device."""
+    with jax.transfer_guard("disallow"):
+        z = shard.zeros((8 * NDEV, 3, 5), jnp.float32, _mesh())
+    assert [s.data.shape for s in z.addressable_shards] == [(8, 3, 5)] * NDEV
+    assert not np.asarray(z).any()
+    agent = FleetQLearning(SyntheticSource(_full_cfg(8 * NDEV)),
+                           cfg=FleetQConfig(), seed=3, mesh=_mesh())
+    assert agent.q.sharding == z.sharding
+    assert not np.asarray(agent.q).any()
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_second_run_under_the_mesh_compiles_nothing(impl):
+    """Every leaf of the placed scenario, its step counter too, comes
+    back from the scan in the layout it went in with, so the next
+    ``run`` call under the mesh reuses the compiled scan."""
+    cfg = FleetConfig(cells=8 * NDEV, users=2, p_r2w=0.05, p_w2r=0.05,
+                      n_edges=2 * NDEV, shard_local=True, n_shards=NDEV)
+    agent = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
+                           seed=3, impl=impl, mesh=_mesh())
+    compiles = []
+
+    def count(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        agent.run(4)
+        first = len(compiles)
+        agent.run(4)
+        agent.run(4)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert first > 0 and len(compiles) == first
+
+
 def test_metrics_accumulator_sharded_update_bit_parity():
     """Standalone obs satellite: the same jitted update on a placed
     accumulator (lane leaves sharded along the fleet axis, histograms

@@ -156,3 +156,65 @@ def test_tabular_scan_compiles_with_link_states(one_chip):
     agent, compiled = _scan(one_chip, 2048, track_links=True)
     assert agent.n_states == 2304
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the ``sharded_train_4chip`` cell: 262,144 cells over four chips, 16
+#: cells an edge, each cell's edge within its chip's block
+MESH_CELLS, MESH_EDGES, MESH_CHIPS = 262144, 16384, 4
+
+
+def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
+    """The fleet scan on the ``('fleet',)`` mesh over the four chips of
+    a described v5e 2x2, at the benchmark's 262,144-cell fleet with
+    shared edges and Markov links: one Q-table shard a chip, arguments
+    and temporaries under 14 GB a chip, and no kernel (GSPMD cannot
+    partition one, so the mesh runs the ``ref`` formulation). The agent
+    is built at 8 cells; its state is described at full size with the
+    layout ``shard.shard_scenario`` and ``place_metrics`` give it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning,
+                             SyntheticSource, shard)
+    from repro.fleet.population import fleet_metrics
+    from repro.fleet.scenarios import FleetScenario
+    from repro.fleet.topology import Topology
+    mesh = Mesh(topo.devices[:MESH_CHIPS], (shard.FLEET_AXIS,))
+
+    def described(x, shape=None, spec=P()):
+        return jax.ShapeDtypeStruct(shape or jnp.shape(x),
+                                    jnp.result_type(x),
+                                    sharding=NamedSharding(mesh, spec))
+
+    def per_cell(x, axis=0):
+        shape = list(jnp.shape(x))
+        shape[axis] = MESH_CELLS
+        return described(x, tuple(shape),
+                         P(*([None] * axis + [shard.FLEET_AXIS])))
+
+    cfg = FleetConfig(cells=8, users=USERS, p_r2w=0.05, p_w2r=0.05,
+                      n_edges=4, shard_local=True, n_shards=MESH_CHIPS)
+    agent = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
+                           impl="ref")
+    agent.source.attach_mesh(mesh)
+    run = jax.jit(agent._make_run(), static_argnums=(6,),
+                  donate_argnums=(0, 1))
+    s = agent.scen
+    scen = FleetScenario(
+        per_cell(s.end_b), per_cell(s.edge_b), per_cell(s.member),
+        per_cell(s.active), described(s.t),
+        Topology(per_cell(s.topo.cell_edge),
+                 described(s.topo.edge_capacity, (MESH_EDGES,)),
+                 described(s.topo.cloud_servers)))
+    mets = fleet_metrics(MESH_CELLS, "tabular").place(per_cell, described)
+    compiled = run.lower(per_cell(agent.q), mets, per_cell(agent.counts),
+                         scen, described(jnp.float32(agent.eps)),
+                         described(agent.key), 8).compile()
+    q_in = compiled.input_shardings[0][0]
+    assert q_in.shard_shape((MESH_CELLS, STATES, ACTIONS))[0] \
+        == MESH_CELLS // MESH_CHIPS
+    assert len(q_in.device_set) == MESH_CHIPS
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
