@@ -332,7 +332,7 @@ def phase_sharded(clock, cells=65536, steps=100, n_devices=4, seed=0):
     single = FleetQLearning(SyntheticSource(cfg), seed=seed,
                             impl=meshed._op_impl)
     log("sharded", f"impl under the mesh resolves to {meshed._op_impl!r} "
-        f"(GSPMD cannot partition a pallas_call); the one-device run uses "
+        f"(update {meshed.update_path!r}); the one-device run uses "
         f"the same path; cells={cells} Q {meshed.q.shape} "
         f"{meshed.q.nbytes / 1e9:.2f} GB")
     for ag in (meshed, single):

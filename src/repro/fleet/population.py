@@ -27,6 +27,7 @@ fleet analogue of ``core.orchestrator.train_agent``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -276,29 +277,46 @@ class FleetQLearning:
         (default) is the fused act+update pair — one Q-row gather
         shared by the TD max and the next step's greedy, which the scan
         then carries instead of re-gathering (the compiled Pallas
-        kernel on TPU, the bit-equivalent fused-jnp formulation
-        elsewhere; see ``kernels.ops.resolve_rl_impl``). ``"xla"`` is
+        kernel on TPU, under a mesh once per device on its block of
+        cells; the bit-equivalent fused-jnp formulation elsewhere; see
+        ``kernels.ops.resolve_rl_impl``). ``"xla"`` is
         the legacy unfused step (separate gather/argmax/scatter HLOs),
         kept as the reference and the ``rl_unfused_*`` benchmark
         baseline. ``"pallas_interpret"`` forces the real kernel in
         interpret mode (parity tests; far too slow for training).
 
         ``spans`` (a ``repro.obs.spans.SpanRecorder``, default none)
-        records each ``run`` call as a ``fleet.run`` span with a
-        ``fleet.run.fetch`` child around the blocking reads. Both are
-        profiler annotations with or without a recorder, and the fused
-        scan's ops sit under the device scopes ``fleet.prologue`` /
-        ``act`` / ``respond`` / ``scenario`` / ``update`` /
-        ``telemetry`` / ``epilogue`` (docs/OBSERVABILITY.md)."""
+        records each ``run`` call as a ``fleet.run`` span (args
+        ``steps``, ``cells`` and ``update``, the update path that runs:
+        ``update_path``) with a ``fleet.run.fetch`` child around the
+        blocking reads. Both are profiler annotations with or without a
+        recorder, and the fused scan's ops sit under the device scopes
+        ``fleet.prologue`` / ``act`` / ``respond`` / ``scenario`` /
+        ``update`` / ``telemetry`` / ``epilogue``
+        (docs/OBSERVABILITY.md)."""
         self.cfg = cfg or FleetQConfig()
         self.spans = spans
         scen, self.source = resolve_source(scen, fleet_cfg, seed, reset_key)
         self.fleet_cfg = getattr(self.source, "cfg", None)
         self.mesh, scen = adopt_mesh(mesh, self.source, scen)
         self.impl = impl
-        self._op_impl = ops.resolve_rl_impl(impl, self.mesh)
+        from repro.fleet import shard
+        # the kernel runs under a mesh once per device, on its block of
+        # cells: only a table that the mesh splits along its cells has one
+        per_shard = (self.mesh is not None and
+                     shard.fleet_spec(self.mesh, (scen.cells,))[0]
+                     is not None)
+        self._op_impl = ops.resolve_rl_impl(impl, self.mesh,
+                                            per_shard=per_shard)
         self._op_kwargs = (None if self._op_impl == "xla"
                            else ops.rl_op_kwargs(self._op_impl))
+        kernel = (self._op_kwargs is not None
+                  and self._op_kwargs["impl"] == "pallas")
+        #: the mesh the kernel path runs on per device (None: no mesh, or
+        #: not the kernel), and the update path the ``fleet.run`` span names
+        self._block_mesh = self.mesh if kernel and per_shard else None
+        self.update_path = self._op_impl + (
+            "_per_shard" if self._block_mesh is not None else "")
         self.spec = SpaceSpec(scen.users)
         self.actions = np.asarray(actions if actions is not None
                                   else default_actions(self.spec))
@@ -309,7 +327,6 @@ class FleetQLearning:
         self._count_states = (users + 1) ** 2
         self._link_states = 2 ** (users + 1) if self.cfg.track_links else 1
         self.n_states = self._count_states * self._link_states
-        from repro.fleet import shard
         # under a mesh each device makes its own block: whole, the table
         # of a fleet sized for the mesh does not fit one device
         self.q = shard.zeros((scen.cells, self.n_states, self.n_actions),
@@ -370,6 +387,8 @@ class FleetQLearning:
         op_kwargs = dict(self._op_kwargs)
         if aligned:
             op_kwargs["n_actions"] = self.n_actions
+        if self._block_mesh is not None:
+            op_kwargs["mesh"] = self._block_mesh
 
         def core(q, mets, counts, scen, eps, key, s, greedy):
             with jax.named_scope("fleet.act"):
@@ -454,12 +473,22 @@ class FleetQLearning:
         update of the PREVIOUS step, instead of once per step. On the
         kernel path the scan carries the table in the kernel's layout:
         aligned once before it (``fleet.prologue``) and given back
-        logical once after it (``fleet.epilogue``)."""
+        logical once after it (``fleet.epilogue``), under a mesh each
+        device its own block of cells, as the update runs."""
         decay, eps_min = self.cfg.eps_decay, self.cfg.eps_min
         if self._op_impl != "xla":
+            from repro.fleet import shard
             kernel = self._op_kwargs["impl"] == "pallas"
             core = self._make_fused_core(aligned=kernel)
             n_states, n_actions = self.n_states, self.n_actions
+            align = shard.per_block(tabular_rl.align_table,
+                                    self._block_mesh)
+            gather = shard.per_block(functools.partial(
+                tabular_rl.gather_rows, n_actions=n_actions),
+                self._block_mesh)
+            unalign = shard.per_block(functools.partial(
+                tabular_rl.unalign_table, n_states=n_states,
+                n_actions=n_actions), self._block_mesh)
 
             def run(q, mets, counts, scen, eps, key, n):
                 def body(carry, _):
@@ -478,8 +507,8 @@ class FleetQLearning:
                 with jax.named_scope("fleet.prologue"):
                     s0 = self._state_index(counts, scen)
                     if kernel:
-                        q = tabular_rl.align_table(q)
-                        rows = tabular_rl.gather_rows(q, s0, n_actions)
+                        q = align(q)
+                        rows = gather(q, s0)
                     else:
                         rows = q[jnp.arange(q.shape[0]), s0]
                     greedy0 = first_argmax_ref(rows)
@@ -489,7 +518,7 @@ class FleetQLearning:
                 q, mets, counts, scen, _, eps, key = carry
                 if kernel:
                     with jax.named_scope("fleet.epilogue"):
-                        q = tabular_rl.unalign_table(q, n_states, n_actions)
+                        q = unalign(q)
                 return (q, mets, counts, scen, eps, key), ms, acc
 
             return run
@@ -524,7 +553,7 @@ class FleetQLearning:
         """Advance every cell by ``n`` steps inside one jitted scan.
         Returns per-step fleet-mean (ms, accuracy) traces of shape (n,)."""
         with span(self.spans, "fleet.run", steps=n,
-                  cells=int(self.q.shape[0])):
+                  cells=int(self.q.shape[0]), update=self.update_path):
             self.key, k = jax.random.split(self.key)
             (self.q, self.metrics, self.counts, self.scen, eps, _), ms, \
                 acc = self._run(self.q, self.metrics, self.counts,

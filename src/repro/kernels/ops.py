@@ -12,6 +12,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref, tabular_rl
 from repro.kernels.decode_attention import decode_attention_kernel
@@ -134,14 +135,18 @@ def selective_scan(u, dt, A, B, C, D, *, impl: str = "pallas", bd: int = 256,
     return y[:, :, :di], h[:, :di]
 
 
-def resolve_rl_impl(impl: str, mesh=None) -> str:
+def resolve_rl_impl(impl: str, mesh=None, per_shard: bool = False) -> str:
     """Resolve a fleet agent's ``impl`` request to an executable path.
 
     ``"xla"`` is the legacy unfused step, untouched. ``"pallas"`` is
     the fused hot path and resolves by capability: the compiled kernel
-    needs a TPU backend, and ``pallas_call`` cannot be partitioned by
-    GSPMD, so under a device mesh (``fleet.shard``) the fused-jnp
-    reference formulation runs instead — it is per-cell elementwise +
+    needs a TPU backend. GSPMD cannot partition a ``pallas_call``, so
+    under a device mesh (``fleet.shard``) the kernel runs only where the
+    caller runs it once per device on that device's block of cells,
+    under ``shard_map`` (``per_shard``: ``FleetQLearning``, whose
+    Q-table is split along its cells). Elsewhere under a mesh
+    (``FleetDQN``, whose ``dqn_head`` is not wrapped) the fused-jnp
+    reference formulation runs instead: it is per-cell elementwise +
     batched gather/scatter + reduces along the unsharded action axis,
     so it stays bit-identical sharded-vs-single-device (the discipline
     ``tests/test_fleet_shard.py`` pins). On non-TPU hosts the same
@@ -155,7 +160,7 @@ def resolve_rl_impl(impl: str, mesh=None) -> str:
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}; expected 'pallas', "
                          "'xla', 'ref', or 'pallas_interpret'")
-    if mesh is not None:
+    if mesh is not None and not per_shard:
         return "ref"
     if jax.default_backend() == "tpu":
         return "pallas"
@@ -186,10 +191,12 @@ def dqn_head_parts(resolved: str, threshold: float) -> dict:
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "gamma", "impl",
-                                             "n_actions", "bc", "interpret"))
+                                             "n_actions", "bc", "interpret",
+                                             "mesh"))
 def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
                          impl: str = "ref", n_actions: Optional[int] = None,
-                         bc: Optional[int] = None, interpret: bool = True):
+                         bc: Optional[int] = None, interpret: bool = True,
+                         mesh=None):
     """Fused tabular act+update: q (cells,S,K) f32, s/a/s2 (cells,)
     int32, r (cells,) f32 -> (q_new, greedy2, td); see
     ``ref.fused_tabular_ref``.
@@ -200,7 +207,20 @@ def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
     ``n_actions``, its logical width, and gets ``q_new`` back in that
     layout; a logical table (``n_actions`` None) is aligned for the call
     and given back logical. ``bc`` defaults to the largest block that
-    fits the kernel's VMEM budget (``tabular_rl.block_cells``)."""
+    fits the kernel's VMEM budget (``tabular_rl.block_cells``).
+
+    ``mesh``: every argument and result is split along its cells over
+    the mesh, and each device runs the update on its own block of cells
+    (``shard_map``), since GSPMD cannot partition a ``pallas_call``.
+    Each block is padded to ``bc`` on its own."""
+    if mesh is not None:
+        per_block = functools.partial(
+            fused_tabular_update, alpha=alpha, gamma=gamma, impl=impl,
+            n_actions=n_actions, bc=bc, interpret=interpret)
+        cells = P(mesh.axis_names)
+        return jax.shard_map(per_block, mesh=mesh, in_specs=cells,
+                             out_specs=cells, check_vma=False)(
+                                 q, s, a, r, s2)
     if impl == "ref":
         if n_actions is not None:
             raise ValueError("the ref path takes a logical table")

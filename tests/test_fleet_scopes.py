@@ -5,7 +5,7 @@ scopes (``fleet.act``, ``fleet.respond``, ``fleet.scenario``,
 ``fleet.update``, ``fleet.telemetry``) and the greedy gather before the
 scan under ``fleet.prologue``; a profiler capture carries these names in
 each device op's ``tf_op``. Each ``run`` call is a ``fleet.run`` host
-span with its ``steps`` and ``cells``. Checked on the CPU: the compiled
+span with its ``steps``, ``cells`` and ``update`` path. Checked on the CPU: the compiled
 module's op metadata, and a CPU profiler capture.
 """
 import glob
@@ -103,14 +103,15 @@ def _capture(path, fn):
 
 def test_run_emits_fleet_run_span_in_a_profiler_capture(tmp_path):
     """No recorder: ``run(5)`` still shows as ``fleet.run`` with its
-    steps and cells, the blocking reads as ``fleet.run.fetch`` inside."""
+    steps, cells and update path (``ref`` on the CPU), the blocking
+    reads as ``fleet.run.fetch`` inside."""
     ag = _agent("pallas")
     ag.run(5)                                     # compile outside
     events = _capture(tmp_path, lambda: ag.run(5))
     runs = [e for e in events if e[0] == "fleet.run"]
     fetch = [e for e in events if e[0] == "fleet.run.fetch"]
     assert len(runs) == 1 and len(fetch) == 1
-    assert runs[0][3] == {"steps": 5, "cells": 64}
+    assert runs[0][3] == {"steps": 5, "cells": 64, "update": "ref"}
     assert runs[0][1] <= fetch[0][1] <= fetch[0][2] <= runs[0][2]
 
 
@@ -125,7 +126,8 @@ def test_run_spans_on_a_recorder_and_unchanged_results():
     np.testing.assert_array_equal(np.asarray(a.q), np.asarray(b.q))
     names = [e["name"] for e in rec.events]
     assert names == ["fleet.run.fetch", "fleet.run"]
-    assert rec.events[1]["args"] == {"steps": 6, "cells": 64}
+    assert rec.events[1]["args"] == {"steps": 6, "cells": 64,
+                                      "update": "ref"}
 
 
 def test_span_without_recorder_enters_a_trace_annotation(tmp_path):

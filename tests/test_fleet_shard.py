@@ -36,11 +36,14 @@ def _mesh():
 def _full_cfg(cells, users=2, shard_local=False):
     """Every scenario dynamic at once: Markov links, Poisson arrivals,
     churn, a shared-edge topology with cloud queueing and edge
-    failures — the hardest case for placement to preserve."""
+    failures — the hardest case for placement to preserve. A
+    shard-local topology has no edge failures, whose reroutes would
+    cross device blocks (``make_topology`` refuses the pair)."""
     return FleetConfig(cells=cells, users=users, p_r2w=0.1, p_w2r=0.2,
                        arrival_rate=1.0, p_join=0.02, p_leave=0.02,
                        n_edges=2 * NDEV, cloud_servers=8.0,
-                       capacity_tiers=(1.0, 2.0), p_edge_fail=0.1,
+                       capacity_tiers=(1.0, 2.0),
+                       p_edge_fail=0.0 if shard_local else 0.1,
                        shard_local=shard_local, n_shards=NDEV)
 
 
@@ -173,6 +176,84 @@ def test_fused_impl_sharded_training_bit_parity():
         np.asarray(meshed.greedy_decisions()))
     if NDEV > 1:
         assert meshed.q.sharding.spec[0] == "fleet"
+
+
+def _count_compiles(fn):
+    """``fn()``, and the number of backend compiles it made."""
+    compiles = []
+
+    def count(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    return len(compiles)
+
+
+def test_kernel_per_shard_training_bit_parity():
+    """The kernel path under a mesh: the real ``tabular_rl`` kernel
+    (interpret mode) runs once per device on its own block of cells
+    under ``shard_map``, and so do the table's layout changes around
+    the scan. 13 cells a device, not a multiple of the kernel's block,
+    over 40 steps in two calls and one single ``step``: the table,
+    counts and greedy decisions are bit-equal to the single-device
+    kernel, and counts and decisions to the single-device ``ref``
+    formulation, whose table the kernel matches up to its
+    fma-contraction ulp (as on one device, ``test_fleet_fused``). The
+    table keeps its layout, and the second call compiles nothing."""
+    cfg = _full_cfg(13 * NDEV, shard_local=True)
+    meshed = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
+                            seed=3, impl="pallas_interpret", mesh=_mesh())
+    single = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
+                            seed=3, impl="pallas_interpret")
+    ref = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
+                         seed=3, impl="ref")
+    assert meshed.update_path == "pallas_interpret_per_shard"
+    assert single.update_path == "pallas_interpret"
+    for ag in (single, ref):
+        ag.run(20)
+        ag.run(20)
+    meshed.run(20)
+    assert _count_compiles(lambda: meshed.run(20)) == 0
+    for ag in (single, ref, meshed):
+        ag.step()
+    np.testing.assert_array_equal(np.asarray(single.q),
+                                  np.asarray(meshed.q))
+    np.testing.assert_allclose(np.asarray(ref.q), np.asarray(meshed.q),
+                               rtol=1e-6, atol=1e-6)
+    for other in (single, ref):
+        np.testing.assert_array_equal(np.asarray(other.counts),
+                                      np.asarray(meshed.counts))
+        np.testing.assert_array_equal(
+            np.asarray(other.greedy_decisions()),
+            np.asarray(meshed.greedy_decisions()))
+    if NDEV > 1:
+        assert meshed.q.sharding.spec[0] == "fleet"
+
+
+def test_tpu_mesh_resolves_the_kernel_for_tabular_only(monkeypatch):
+    """On a TPU backend ``impl='pallas'`` under a mesh runs the kernel
+    per shard for ``FleetQLearning``, whose table the mesh splits along
+    its cells; ``FleetDQN``, whose head is not wrapped, keeps ``ref``.
+    Nothing is compiled here: the backend is only named."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _mesh()
+    assert ops.resolve_rl_impl("pallas", mesh, per_shard=True) == "pallas"
+    assert ops.resolve_rl_impl("pallas", mesh) == "ref"
+    tab = FleetQLearning(SyntheticSource(_full_cfg(8 * NDEV,
+                                                   shard_local=True)),
+                         cfg=FleetQConfig(), seed=3, mesh=mesh)
+    assert tab._op_impl == "pallas"
+    assert tab._op_kwargs == {"impl": "pallas", "interpret": False}
+    assert tab.update_path == "pallas_per_shard"
+    dqn = FleetDQN(SyntheticSource(FleetConfig(cells=8 * NDEV, users=2)),
+                   cfg=FleetDQNConfig(), seed=5, mesh=mesh)
+    assert dqn._op_impl == "ref"
 
 
 def test_zeros_are_made_on_each_device():

@@ -163,14 +163,13 @@ def test_tabular_scan_compiles_with_link_states(one_chip):
 MESH_CELLS, MESH_EDGES, MESH_CHIPS = 262144, 16384, 4
 
 
-def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
+def _sharded_scan(topo, kernel: bool):
     """The fleet scan on the ``('fleet',)`` mesh over the four chips of
     a described v5e 2x2, at the benchmark's 262,144-cell fleet with
-    shared edges and Markov links: one Q-table shard a chip, arguments
-    and temporaries under 14 GB a chip, and no kernel (GSPMD cannot
-    partition one, so the mesh runs the ``ref`` formulation). The agent
-    is built at 8 cells; its state is described at full size with the
-    layout ``shard.shard_scenario`` and ``place_metrics`` give it."""
+    shared edges and Markov links, compiled: the ``ref`` update, or the
+    compiled kernel (``kernel``). The agent is built at 8 cells; its
+    state is described at full size with the layout
+    ``shard.shard_scenario`` and ``place_metrics`` give it."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.fleet import (FleetConfig, FleetQConfig, FleetQLearning,
@@ -196,6 +195,9 @@ def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
     agent = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
                            impl="ref")
     agent.source.attach_mesh(mesh)
+    if kernel:
+        agent._op_kwargs = ops.rl_op_kwargs("pallas")
+        agent._block_mesh = mesh
     run = jax.jit(agent._make_run(), static_argnums=(6,),
                   donate_argnums=(0, 1))
     s = agent.scen
@@ -206,9 +208,16 @@ def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
                  described(s.topo.edge_capacity, (MESH_EDGES,)),
                  described(s.topo.cloud_servers)))
     mets = fleet_metrics(MESH_CELLS, "tabular").place(per_cell, described)
-    compiled = run.lower(per_cell(agent.q), mets, per_cell(agent.counts),
-                         scen, described(jnp.float32(agent.eps)),
-                         described(agent.key), 8).compile()
+    return run.lower(per_cell(agent.q), mets, per_cell(agent.counts),
+                     scen, described(jnp.float32(agent.eps)),
+                     described(agent.key), 8).compile()
+
+
+def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
+    """The four-chip scan with the ``ref`` update (what a mesh resolves
+    to off a TPU, and for ``FleetDQN``): one Q-table shard a chip,
+    arguments and temporaries under 14 GB a chip, and no kernel."""
+    compiled = _sharded_scan(topo, kernel=False)
     q_in = compiled.input_shardings[0][0]
     assert q_in.shard_shape((MESH_CELLS, STATES, ACTIONS))[0] \
         == MESH_CELLS // MESH_CHIPS
@@ -218,3 +227,29 @@ def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text
+
+
+def test_sharded_kernel_scan_at_benchmark_size_runs_one_kernel_a_chip(topo):
+    """The same four-chip scan with the kernel path forced, as a TPU
+    backend resolves ``impl="pallas"`` under a mesh: the ``tabular_rl``
+    kernel runs once a chip on its 65,536 cells under ``shard_map``,
+    one custom call under ``fleet.update`` in the scan's body; the
+    contention sums are still all-reduced; and each chip holds the
+    table in the kernel's layout, padded to 40 x 256, and at most 10%
+    more (no whole-shard relayouts)."""
+    import re
+
+    compiled = _sharded_scan(topo, kernel=True)
+    q_in = compiled.input_shardings[0][0]
+    assert q_in.shard_shape((MESH_CELLS, STATES, ACTIONS))[0] \
+        == MESH_CELLS // MESH_CHIPS
+    text = compiled.as_text()
+    calls = re.findall(r"%(fused_tabular_update(?:\.\d+)?) = .*? "
+                       r"custom-call\(.*op_name=\"([^\"]*)\"", text)
+    assert len(calls) == 1
+    assert "/fleet.update/" in calls[0][1]
+    assert "/while/body/" in calls[0][1]
+    assert "all-reduce" in text
+    padded_shard = MESH_CELLS // MESH_CHIPS * 40 * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 1.1 * padded_shard
