@@ -82,17 +82,14 @@ def bench_fleet_env(host_steps: int, cells: int = CELLS,
 
 
 def bench_fleet_rl(host_steps: int, cells: int = CELLS,
-                   chunk: int = 50, impl: str = "pallas") -> float:
-    """Full RL loop (greedy/explore + env + TD update) env-steps/sec.
-    ``impl`` selects the hot path: ``'pallas'`` = the fused act+update
-    op (ISSUE-10), ``'xla'`` = the legacy unfused step. Measures the
-    bare loop (``metrics=False``): the telemetry accumulator adds the
-    same constant cost to both impls and its overhead is gated
-    separately (``fleet_dqn_obs_overhead_x``)."""
+                   chunk: int = 50) -> float:
+    """Full RL loop (greedy/explore + env + TD update) env-steps/sec on
+    the fused act+update op. Measures the bare loop (``metrics=False``):
+    the telemetry accumulator's overhead is gated separately
+    (``fleet_dqn_obs_overhead_x``)."""
     scen = mixed_table5_fleet(jax.random.PRNGKey(0), cells, USERS)
     agent = FleetQLearning(scen, FleetConfig(cells=cells, users=USERS),
-                           FleetQConfig(eps_decay=0.0), impl=impl,
-                           metrics=False)
+                           FleetQConfig(eps_decay=0.0), metrics=False)
     agent.run(chunk)                               # compile
     jax.block_until_ready(agent.q)
     n_chunks = max(1, host_steps // chunk)
@@ -115,16 +112,10 @@ def main(tiny: bool = False):
         tr_cells, tr_steps, chunk = 64, 20000, 50
     scalar_sps = bench_scalar(sc_steps)
     fleet_sps = bench_fleet_env(env_steps, cells, chunk)
-    # fused-vs-unfused pair: interleaved best-of-N — alternating the two
-    # impls equalizes load drift across the pair, best-of filters
-    # scheduler noise (the ratio is the headline, not the absolutes)
+    # best-of-N filters scheduler noise
     reps = 1 if tiny else 3
-    rl_f, rl_u = [], []
-    for _ in range(reps):
-        rl_f.append(bench_fleet_rl(rl_steps, cells, chunk))
-        rl_u.append(bench_fleet_rl(rl_steps, cells, chunk, impl="xla"))
-    rl_sps, rl_unfused_sps = max(rl_f), max(rl_u)
-    rl_fused_x = rl_sps / rl_unfused_sps
+    rl_sps = max(bench_fleet_rl(rl_steps, cells, chunk)
+                 for _ in range(reps))
     speedup = fleet_sps / scalar_sps
     emit("fleet_scalar_env_steps", 1e6 / scalar_sps,
          f"steps_per_s={scalar_sps:.0f}")
@@ -134,10 +125,6 @@ def main(tiny: bool = False):
     emit("fleet_rl_steps", 1e6 / rl_sps,
          f"steps_per_s={rl_sps:.0f} (act+env+TD, {rl_sps/scalar_sps:.1f}x "
          f"scalar env alone)")
-    emit("fleet_rl_steps_unfused", 1e6 / rl_unfused_sps,
-         f"steps_per_s={rl_unfused_sps:.0f} (legacy impl='xla' step)")
-    emit("fleet_rl_fused_speedup", rl_fused_x,
-         "x fused act+update vs unfused (ISSUE-10 target >=2x)")
 
     # population training: converged cells / second (2-user cells)
     scen = mixed_table5_fleet(jax.random.PRNGKey(1), tr_cells, 2)
@@ -154,8 +141,6 @@ def main(tiny: bool = False):
         "fleet_env_steps_per_s": fleet_sps,
         "fleet_rl_steps_per_s": rl_sps,
         "rl_fused_tabular_steps_per_s": rl_sps,
-        "rl_unfused_tabular_steps_per_s": rl_unfused_sps,
-        "rl_fused_tabular_speedup_x": rl_fused_x,
         "speedup_x": speedup,
         "train_frac_converged": res.frac_converged,
         "train_converged_cells_per_s": res.cells_per_second,

@@ -100,10 +100,6 @@ def main() -> None:
             "rl_steps_per_s": tp.get("fleet_rl_steps_per_s"),
             "rl_fused_tabular_steps_per_s":
                 tp.get("rl_fused_tabular_steps_per_s"),
-            "rl_unfused_tabular_steps_per_s":
-                tp.get("rl_unfused_tabular_steps_per_s"),
-            "rl_fused_tabular_speedup_x":
-                tp.get("rl_fused_tabular_speedup_x"),
             "dqn_rl_steps_per_s": dqn.get("dqn_rl_steps_per_s"),
             "rl_fused_dqn_steps_per_s": dqn.get("rl_fused_dqn_steps_per_s"),
             "rl_unfused_dqn_steps_per_s":

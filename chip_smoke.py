@@ -123,8 +123,10 @@ def _check_kernel_impl(agent, impl: str, phase: str) -> None:
     check(agent._op_impl == impl,
           f"{phase}: impl={impl!r} resolved to {agent._op_impl!r}")
     if impl == "pallas":      # a chip run must never get interpret mode
-        check(agent._op_kwargs == {"impl": "pallas", "interpret": False},
-              f"{phase}: kernel kwargs {agent._op_kwargs}")
+        path = getattr(agent, "path", None)       # the tabular agent's
+        interpret = (path.interpret if path is not None
+                     else agent._op_kwargs["interpret"])
+        check(not interpret, f"{phase}: the kernel runs interpreted")
 
 
 # ---------------------------------------------------------------- phases --
@@ -142,7 +144,7 @@ def phase_tabular(clock, cells=65536, steps=300, parity_cells=4096,
     _check_kernel_impl(tab, impl, "tabular")
     check(tab.q.shape == (cells, 36, 243), f"Q-table {tab.q.shape}")
     log("tabular", f"impl={tab._op_impl} "
-        f"interpret={tab._op_kwargs.get('interpret')} cells={cells} "
+        f"interpret={tab.path.interpret} cells={cells} "
         f"Q {tab.q.shape} f32 = {tab.q.nbytes / 1e9:.2f} GB; "
         f"device {device_bytes()}")
     first, steady, ms = _timed_runs(tab, steps)

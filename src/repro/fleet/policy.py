@@ -346,12 +346,14 @@ class FleetDQN:
             raise ValueError(f"unknown net form {self.cfg.net!r} "
                              "(expected 'shared' or 'cell')")
         self.impl = impl
-        resolved = ops.resolve_rl_impl(impl, self.mesh)
-        if self.cfg.net != "shared":
-            resolved = "xla"        # fused head is shared-encoder only
+        # "xla": the legacy head, the only one of the net="cell" encoder
+        resolved = ("xla" if impl == "xla" or self.cfg.net != "shared"
+                    else ops.resolve_rl_impl(impl, self.mesh))
         self._op_impl = resolved
-        self._op_kwargs = (None if resolved == "xla"
-                           else ops.rl_op_kwargs(resolved))
+        self._op_kwargs = (None if resolved == "xla" else
+                           {"impl": "ref"} if resolved == "ref" else
+                           {"impl": "pallas",
+                            "interpret": resolved == "pallas_interpret"})
         #: where each part of the act head runs: the fused head splits
         #: kernel and XLA work (``ops.dqn_head_parts``)
         self.op_parts = ({"head": "xla"} if resolved == "xla" else

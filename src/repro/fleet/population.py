@@ -27,7 +27,6 @@ fleet analogue of ``core.orchestrator.train_agent``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Optional
 
@@ -38,7 +37,7 @@ import numpy as np
 from repro.core.spaces import SpaceSpec, restricted_actions
 from repro.fleet import dynamics, topology
 from repro.fleet.scenarios import FleetConfig, FleetScenario
-from repro.kernels import ops, tabular_rl
+from repro.kernels import ops
 from repro.kernels.ref import first_argmax_ref
 from repro.obs.metrics import MetricDef, MetricsAccumulator
 from repro.obs.spans import span
@@ -273,17 +272,13 @@ class FleetQLearning:
         (``window_len`` steps per slot) to every stream so
         ``metrics_summary()`` carries the learning curve.
 
-        ``impl`` selects the hot-path implementation: ``"pallas"``
-        (default) is the fused act+update pair — one Q-row gather
-        shared by the TD max and the next step's greedy, which the scan
-        then carries instead of re-gathering (the compiled Pallas
-        kernel on TPU, under a mesh once per device on its block of
-        cells; the bit-equivalent fused-jnp formulation elsewhere; see
-        ``kernels.ops.resolve_rl_impl``). ``"xla"`` is
-        the legacy unfused step (separate gather/argmax/scatter HLOs),
-        kept as the reference and the ``rl_unfused_*`` benchmark
-        baseline. ``"pallas_interpret"`` forces the real kernel in
-        interpret mode (parity tests; far too slow for training).
+        ``impl`` selects where the fused act+update runs (one Q-row
+        gather shared by the TD max and the next step's greedy):
+        ``"pallas"`` (default) is the compiled kernel on TPU, under a
+        mesh once per device on its block of cells, and ``"ref"`` (the
+        fused-jnp formulation) elsewhere; ``"pallas_interpret"`` forces
+        the kernel in interpret mode (parity tests). ``self.path``
+        (``kernels.ops.TabularPath``) holds the choice.
 
         ``spans`` (a ``repro.obs.spans.SpanRecorder``, default none)
         records each ``run`` call as a ``fleet.run`` span (args
@@ -299,24 +294,7 @@ class FleetQLearning:
         scen, self.source = resolve_source(scen, fleet_cfg, seed, reset_key)
         self.fleet_cfg = getattr(self.source, "cfg", None)
         self.mesh, scen = adopt_mesh(mesh, self.source, scen)
-        self.impl = impl
         from repro.fleet import shard
-        # the kernel runs under a mesh once per device, on its block of
-        # cells: only a table that the mesh splits along its cells has one
-        per_shard = (self.mesh is not None and
-                     shard.fleet_spec(self.mesh, (scen.cells,))[0]
-                     is not None)
-        self._op_impl = ops.resolve_rl_impl(impl, self.mesh,
-                                            per_shard=per_shard)
-        self._op_kwargs = (None if self._op_impl == "xla"
-                           else ops.rl_op_kwargs(self._op_impl))
-        kernel = (self._op_kwargs is not None
-                  and self._op_kwargs["impl"] == "pallas")
-        #: the mesh the kernel path runs on per device (None: no mesh, or
-        #: not the kernel), and the update path the ``fleet.run`` span names
-        self._block_mesh = self.mesh if kernel and per_shard else None
-        self.update_path = self._op_impl + (
-            "_per_shard" if self._block_mesh is not None else "")
         self.spec = SpaceSpec(scen.users)
         self.actions = np.asarray(actions if actions is not None
                                   else default_actions(self.spec))
@@ -327,6 +305,14 @@ class FleetQLearning:
         self._count_states = (users + 1) ** 2
         self._link_states = 2 ** (users + 1) if self.cfg.track_links else 1
         self.n_states = self._count_states * self._link_states
+        # the kernel runs under a mesh once per device, on its block of
+        # cells: only a table that the mesh splits along its cells has one
+        per_shard = (self.mesh is not None and
+                     shard.fleet_spec(self.mesh, (scen.cells,))[0]
+                     is not None)
+        #: where the TD update runs and the table's layout around it
+        self.path = ops.tabular_path(impl, self.n_states, self.n_actions,
+                                     self.mesh, per_shard=per_shard)
         # under a mesh each device makes its own block: whole, the table
         # of a fleet sized for the mesh does not fit one device
         self.q = shard.zeros((scen.cells, self.n_states, self.n_actions),
@@ -352,6 +338,14 @@ class FleetQLearning:
                             donate_argnums=don)
         self._greedy = jax.jit(self._make_greedy())
 
+    @property
+    def _op_impl(self) -> str:
+        return self.path.impl
+
+    @property
+    def update_path(self) -> str:
+        return self.path.name
+
     # ------------------------------------------------------------------
     def _state_index(self, counts, scen: FleetScenario):
         users = scen.users
@@ -362,38 +356,25 @@ class FleetQLearning:
             s = s * self._link_states + packed
         return s
 
-    def _explore(self, greedy, eps, k_exp):
-        """Shared eps-greedy action draw: one uniform drives both the
-        explore decision and, conditioned on u < eps, the (still
-        uniform) random action u/eps — identical RNG consumption on the
-        fused and unfused paths, so trajectories match across impls."""
-        n_actions = self.n_actions
-        u = jax.random.uniform(k_exp, greedy.shape)
-        rand = jnp.minimum((u / jnp.maximum(eps, 1e-9)
-                            * n_actions).astype(jnp.int32),
-                           n_actions - 1)
-        return jnp.where(u < eps, rand, greedy)
-
-    def _make_fused_core(self, aligned: bool = False):
+    def _make_fused_core(self):
         """env step + fused TD update from a precomputed ``(s, greedy)``
-        pair — the body shared by the fused single-step and the fused
-        scan (which carries ``greedy2`` instead of re-gathering the
-        ``s2`` Q-row next step). Splits the key exactly like the legacy
-        step, so fused and unfused trajectories use identical RNG.
-        ``aligned``: ``q`` comes in the kernel's layout
-        (``tabular_rl.align_table``) and stays in it."""
-        cfg, pu = self.cfg, self.pu_table
-        advance = self.source.step
-        op_kwargs = dict(self._op_kwargs)
-        if aligned:
-            op_kwargs["n_actions"] = self.n_actions
-        if self._block_mesh is not None:
-            op_kwargs["mesh"] = self._block_mesh
+        pair — the body shared by the single step and the scan (which
+        carries ``greedy2`` instead of re-gathering the ``s2`` Q-row
+        next step). ``q`` is in the layout of ``self.path``
+        (``path.enter``) and stays in it."""
+        cfg, pu, path = self.cfg, self.pu_table, self.path
+        advance, n_actions = self.source.step, self.n_actions
 
         def core(q, mets, counts, scen, eps, key, s, greedy):
             with jax.named_scope("fleet.act"):
                 k_exp, k_noise, k_scen = jax.random.split(key, 3)
-                a = self._explore(greedy, eps, k_exp)          # (cells,)
+                # eps-greedy: one uniform drives both the explore decision
+                # and, given u < eps, the (still uniform) action u/eps
+                u = jax.random.uniform(k_exp, greedy.shape)
+                rand = jnp.minimum((u / jnp.maximum(eps, 1e-9)
+                                    * n_actions).astype(jnp.int32),
+                                   n_actions - 1)
+                a = jnp.where(u < eps, rand, greedy)           # (cells,)
                 per_user = pu[a]                               # (cells, N)
             with jax.named_scope("fleet.respond"):
                 mean_ms, acc, counts2 = simulate_responses(
@@ -404,9 +385,9 @@ class FleetQLearning:
                 scen2, _ = advance(k_scen, scen)
                 s2 = self._state_index(counts2, scen2)
             with jax.named_scope("fleet.update"):
-                q, greedy2, td = ops.fused_tabular_update(
-                    q, s, a, r, s2, alpha=cfg.alpha, gamma=cfg.gamma,
-                    **op_kwargs)
+                q, greedy2, td = path.update(q, s, a, r, s2,
+                                             alpha=cfg.alpha,
+                                             gamma=cfg.gamma)
             if mets is not None:   # trace-time constant, no host sync
                 with jax.named_scope("fleet.telemetry"):
                     mets = mets.update({"reward": r, "mean_ms": mean_ms,
@@ -418,124 +399,54 @@ class FleetQLearning:
         return core
 
     def _make_step(self):
-        if self._op_impl != "xla":
-            core = self._make_fused_core()
-
-            def step(q, mets, counts, scen, eps, key):
-                s = self._state_index(counts, scen)
-                greedy = first_argmax_ref(q[jnp.arange(q.shape[0]), s])
-                q, mets, counts2, scen2, _, info = core(
-                    q, mets, counts, scen, eps, key, s, greedy)
-                return q, mets, counts2, scen2, info
-
-            return step
-        cfg, pu = self.cfg, self.pu_table
-        advance = self.source.step          # jit-pure ScenarioSource step
-        n_actions = self.n_actions
+        core, path = self._make_fused_core(), self.path
 
         def step(q, mets, counts, scen, eps, key):
-            cells = jnp.arange(q.shape[0])
-            k_exp, k_noise, k_scen = jax.random.split(key, 3)
+            q = path.enter(q)
             s = self._state_index(counts, scen)
-            q_s = q[cells, s]                                  # (cells, K)
-            greedy = q_s.argmax(-1)
-            # one uniform drives both the explore decision and, conditioned
-            # on u < eps, the (still uniform) random action u/eps
-            u = jax.random.uniform(k_exp, greedy.shape)
-            rand = jnp.minimum((u / jnp.maximum(eps, 1e-9)
-                                * n_actions).astype(jnp.int32),
-                               n_actions - 1)
-            a = jnp.where(u < eps, rand, greedy)               # (cells,)
-            per_user = pu[a]                                   # (cells, N)
-            # simulate every cell's response under its own conditions
-            mean_ms, acc, counts2 = simulate_responses(k_noise, scen,
-                                                       per_user, cfg.noise)
-            r = dynamics.reward(mean_ms, acc, cfg.accuracy_threshold,
-                                xp=jnp)
-            # exogenous transition + TD update against the next state
-            scen2, _ = advance(k_scen, scen)
-            s2 = self._state_index(counts2, scen2)
-            td = r + cfg.gamma * q[cells, s2].max(-1) - q[cells, s, a]
-            q = q.at[cells, s, a].add(cfg.alpha * td)
-            if mets is not None:       # trace-time constant, no host sync
-                mets = mets.update({"reward": r, "mean_ms": mean_ms,
-                                    "td_abs": jnp.abs(td), "epsilon": eps})
-            info = {"mean_ms": mean_ms, "mean_acc": acc, "reward": r}
-            return q, mets, counts2, scen2, info
+            greedy = first_argmax_ref(path.rows(q, s))
+            q, mets, counts2, scen2, _, info = core(
+                q, mets, counts, scen, eps, key, s, greedy)
+            return path.leave(q), mets, counts2, scen2, info
 
         return step
 
     def _make_run(self):
         """n environment steps for the whole fleet in ONE jitted lax.scan
         call (amortizes dispatch; donation keeps the table in place).
-        The fused path carries each step's ``greedy2`` through the scan
-        — the act-side Q-row gather+argmax happens once, in the fused
-        update of the PREVIOUS step, instead of once per step. On the
-        kernel path the scan carries the table in the kernel's layout:
-        aligned once before it (``fleet.prologue``) and given back
-        logical once after it (``fleet.epilogue``), under a mesh each
-        device its own block of cells, as the update runs."""
+        The scan carries each step's ``greedy2`` (the act-side row
+        gather happens once, in the PREVIOUS step's update) and the
+        table in the layout of ``self.path``, brought in before it
+        (``fleet.prologue``) and taken out after it
+        (``fleet.epilogue``)."""
         decay, eps_min = self.cfg.eps_decay, self.cfg.eps_min
-        if self._op_impl != "xla":
-            from repro.fleet import shard
-            kernel = self._op_kwargs["impl"] == "pallas"
-            core = self._make_fused_core(aligned=kernel)
-            n_states, n_actions = self.n_states, self.n_actions
-            align = shard.per_block(tabular_rl.align_table,
-                                    self._block_mesh)
-            gather = shard.per_block(functools.partial(
-                tabular_rl.gather_rows, n_actions=n_actions),
-                self._block_mesh)
-            unalign = shard.per_block(functools.partial(
-                tabular_rl.unalign_table, n_states=n_states,
-                n_actions=n_actions), self._block_mesh)
-
-            def run(q, mets, counts, scen, eps, key, n):
-                def body(carry, _):
-                    q, mets, counts, scen, greedy, eps, key = carry
-                    with jax.named_scope("fleet.act"):
-                        key, k = jax.random.split(key)
-                        s = self._state_index(counts, scen)
-                    q, mets, counts, scen, greedy, info = core(
-                        q, mets, counts, scen, eps, k, s, greedy)
-                    with jax.named_scope("fleet.act"):
-                        eps = jnp.maximum(eps_min, eps * (1.0 - decay))
-                    with jax.named_scope("fleet.telemetry"):
-                        trace = (info["mean_ms"].mean(),
-                                 info["mean_acc"].mean())
-                    return (q, mets, counts, scen, greedy, eps, key), trace
-                with jax.named_scope("fleet.prologue"):
-                    s0 = self._state_index(counts, scen)
-                    if kernel:
-                        q = align(q)
-                        rows = gather(q, s0)
-                    else:
-                        rows = q[jnp.arange(q.shape[0]), s0]
-                    greedy0 = first_argmax_ref(rows)
-                carry, (ms, acc) = jax.lax.scan(
-                    body, (q, mets, counts, scen, greedy0, eps, key),
-                    None, length=n)
-                q, mets, counts, scen, _, eps, key = carry
-                if kernel:
-                    with jax.named_scope("fleet.epilogue"):
-                        q = unalign(q)
-                return (q, mets, counts, scen, eps, key), ms, acc
-
-            return run
-        step = self._make_step()
+        core, path = self._make_fused_core(), self.path
 
         def run(q, mets, counts, scen, eps, key, n):
             def body(carry, _):
-                q, mets, counts, scen, eps, key = carry
-                key, k = jax.random.split(key)
-                q, mets, counts, scen, info = step(q, mets, counts, scen,
-                                                   eps, k)
-                eps = jnp.maximum(eps_min, eps * (1.0 - decay))
-                return ((q, mets, counts, scen, eps, key),
-                        (info["mean_ms"].mean(), info["mean_acc"].mean()))
+                q, mets, counts, scen, greedy, eps, key = carry
+                with jax.named_scope("fleet.act"):
+                    key, k = jax.random.split(key)
+                    s = self._state_index(counts, scen)
+                q, mets, counts, scen, greedy, info = core(
+                    q, mets, counts, scen, eps, k, s, greedy)
+                with jax.named_scope("fleet.act"):
+                    eps = jnp.maximum(eps_min, eps * (1.0 - decay))
+                with jax.named_scope("fleet.telemetry"):
+                    trace = (info["mean_ms"].mean(),
+                             info["mean_acc"].mean())
+                return (q, mets, counts, scen, greedy, eps, key), trace
+            with jax.named_scope("fleet.prologue"):
+                s0 = self._state_index(counts, scen)
+                q = path.enter(q)
+                greedy0 = first_argmax_ref(path.rows(q, s0))
             carry, (ms, acc) = jax.lax.scan(
-                body, (q, mets, counts, scen, eps, key), None, length=n)
-            return carry, ms, acc
+                body, (q, mets, counts, scen, greedy0, eps, key),
+                None, length=n)
+            q, mets, counts, scen, _, eps, key = carry
+            with jax.named_scope("fleet.epilogue"):
+                q = path.leave(q)
+            return (q, mets, counts, scen, eps, key), ms, acc
 
         return run
 

@@ -45,12 +45,12 @@ rules) over a 1-D ``('fleet',)`` mesh:
   (``kernels.ops.resolve_rl_impl``) runs the compiled kernel under a
   mesh only where it is run once per device, on that device's block of
   cells. ``FleetQLearning`` does so: on a TPU backend, with its Q-table
-  split along the cells, ``ops.fused_tabular_update(..., mesh=)`` runs
-  the ``tabular_rl`` kernel under ``shard_map`` on each device's block,
+  split along the cells, its ``kernels.ops.TabularPath`` runs the
+  ``tabular_rl`` kernel under ``shard_map`` on each device's block,
   each padded to the kernel's block size on its own, and the table's
   changes into and out of the kernel's layout around the scan
-  (``tabular_rl.align_table`` / ``gather_rows`` / ``unalign_table``)
-  run per block too (``per_block``). The scan carries the aligned
+  (``enter`` / ``rows`` / ``leave``) run per block too
+  (``ops.per_block``). The scan carries the aligned
   table split along the cells from start to end, and no op ever
   reshapes a whole device's shard as one array. ``FleetDQN``'s
   ``dqn_head`` is not wrapped: under a mesh it resolves to the fused
@@ -82,7 +82,7 @@ from repro.fleet.topology import Topology, shard_blocks
 
 __all__ = [
     "FLEET_AXIS", "fleet_mesh", "fleet_spec", "shard_array", "zeros",
-    "per_block", "constrain_array", "replicate", "shard_topology",
+    "constrain_array", "replicate", "shard_topology",
     "shard_scenario", "constrain_scenario", "shard_replay",
     "local_contention", "local_expected_response", "check_shard_local",
 ]
@@ -143,19 +143,6 @@ def zeros(shape, dtype, mesh: Optional[Mesh], axis: int = 0,
         return jnp.zeros(shape, dtype)
     out = NamedSharding(mesh, fleet_spec(mesh, shape, axis, logical))
     return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=out)()
-
-
-def per_block(fn, mesh: Optional[Mesh]):
-    """``fn`` run by each device on its own block of cells: every
-    argument and result of it split along its first axis over the fleet
-    axis (``shard_map``), so that ``fn`` sees one device's block as a
-    whole array. ``fn`` itself when ``mesh`` is None. Unchecked for
-    replication (``check_vma=False``): ``fn`` may carry per-block values
-    through loops from fresh buffers, or call a ``pallas_call``."""
-    if mesh is None:
-        return fn
-    return shard_map(fn, mesh=mesh, in_specs=P(FLEET_AXIS),
-                     out_specs=P(FLEET_AXIS), check_vma=False)
 
 
 def replicate(tree, mesh: Optional[Mesh]):

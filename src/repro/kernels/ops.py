@@ -7,12 +7,13 @@ is also the path the SPMD dry-run lowers (see DESIGN.md §3).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ref, tabular_rl
 from repro.kernels.decode_attention import decode_attention_kernel
@@ -138,28 +139,21 @@ def selective_scan(u, dt, A, B, C, D, *, impl: str = "pallas", bd: int = 256,
 def resolve_rl_impl(impl: str, mesh=None, per_shard: bool = False) -> str:
     """Resolve a fleet agent's ``impl`` request to an executable path.
 
-    ``"xla"`` is the legacy unfused step, untouched. ``"pallas"`` is
-    the fused hot path and resolves by capability: the compiled kernel
-    needs a TPU backend. GSPMD cannot partition a ``pallas_call``, so
-    under a device mesh (``fleet.shard``) the kernel runs only where the
-    caller runs it once per device on that device's block of cells,
-    under ``shard_map`` (``per_shard``: ``FleetQLearning``, whose
-    Q-table is split along its cells). Elsewhere under a mesh
-    (``FleetDQN``, whose ``dqn_head`` is not wrapped) the fused-jnp
-    reference formulation runs instead: it is per-cell elementwise +
-    batched gather/scatter + reduces along the unsharded action axis,
-    so it stays bit-identical sharded-vs-single-device (the discipline
-    ``tests/test_fleet_shard.py`` pins). On non-TPU hosts the same
-    reference formulation IS the fused win: one row-gather shared by
-    act and update, and the two-reduce ``first_argmax_ref``.
-    ``"pallas_interpret"`` forces the real kernel in interpret mode
-    (CPU CI parity runs; far too slow for production loops).
-    """
-    if impl in ("xla", "ref", "pallas_interpret"):
+    ``"pallas"`` resolves by capability: the compiled kernel needs a TPU
+    backend and, since GSPMD cannot partition a ``pallas_call``, under a
+    device mesh a caller that runs it once per device on its block of
+    cells (``per_shard``: ``FleetQLearning``, whose Q-table is split
+    along its cells). Elsewhere the fused-jnp reference formulation
+    runs (``"ref"``): per-cell elementwise + batched gather/scatter +
+    reduces along the unsharded action axis, bit-identical sharded and
+    single-device (``tests/test_fleet_shard.py``), and off a TPU the
+    fused win itself. ``"pallas_interpret"`` forces the real kernel in
+    interpret mode (CPU parity runs; far too slow for training)."""
+    if impl in ("ref", "pallas_interpret"):
         return impl
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}; expected 'pallas', "
-                         "'xla', 'ref', or 'pallas_interpret'")
+                         "'ref', or 'pallas_interpret'")
     if mesh is not None and not per_shard:
         return "ref"
     if jax.default_backend() == "tpu":
@@ -167,15 +161,84 @@ def resolve_rl_impl(impl: str, mesh=None, per_shard: bool = False) -> str:
     return "ref"
 
 
-def rl_op_kwargs(resolved: str) -> dict:
-    """kwargs for the fused ops matching a ``resolve_rl_impl`` result."""
-    if resolved == "ref":
-        return {"impl": "ref"}
-    if resolved == "pallas":
-        return {"impl": "pallas", "interpret": False}
-    if resolved == "pallas_interpret":
-        return {"impl": "pallas", "interpret": True}
-    raise ValueError(f"no fused op path for resolved impl {resolved!r}")
+def per_block(fn, mesh: Optional[Mesh]):
+    """``fn`` run by each device on its own block of cells, every
+    argument and result split along its first axis (``shard_map``,
+    unchecked: ``fn`` may call a ``pallas_call``); ``fn`` itself when
+    ``mesh`` is None."""
+    if mesh is None:
+        return fn
+    cells = P(mesh.axis_names)
+    return jax.shard_map(fn, mesh=mesh, in_specs=cells, out_specs=cells,
+                         check_vma=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class TabularPath:
+    """Where a fleet's ``(cells, n_states, n_actions)`` Q-table is
+    updated, and its layout meanwhile. ``impl``: ``"ref"`` (the fused-jnp
+    formulation on the logical table), or the ``tabular_rl`` kernel,
+    compiled (``"pallas"``) or not (``"pallas_interpret"``), on the
+    table in its own layout; ``mesh``: the kernel and the layout changes
+    run once per device, on its block of cells. A caller brings the
+    table in once (``enter``), reads rows (``rows``) and updates it
+    (``update``) as often as it likes, and takes it out (``leave``)."""
+    impl: str
+    n_states: int
+    n_actions: int
+    mesh: Optional[Mesh] = None
+
+    @property
+    def kernel(self) -> bool:
+        return self.impl != "ref"
+
+    @property
+    def interpret(self) -> bool:
+        return self.impl == "pallas_interpret"
+
+    @property
+    def name(self) -> str:
+        return self.impl + ("_per_shard" if self.mesh is not None else "")
+
+    def enter(self, q):
+        """The logical table in the layout ``update`` takes."""
+        if not self.kernel:
+            return q
+        return per_block(tabular_rl.align_table, self.mesh)(q)
+
+    def rows(self, q, s):
+        """``(cells, n_actions)``: row ``s[c]`` of each cell's table."""
+        if not self.kernel:
+            return q[jnp.arange(q.shape[0]), s]
+        return per_block(functools.partial(
+            tabular_rl.gather_rows, n_actions=self.n_actions),
+            self.mesh)(q, s)
+
+    def leave(self, q):
+        """The logical table (``enter``'s inverse)."""
+        if not self.kernel:
+            return q
+        return per_block(functools.partial(
+            tabular_rl.unalign_table, n_states=self.n_states,
+            n_actions=self.n_actions), self.mesh)(q)
+
+    def update(self, q, s, a, r, s2, *, alpha: float, gamma: float):
+        """``fused_tabular_update`` in this layout, looked up when
+        traced: a replacement of ``ops.fused_tabular_update`` counts."""
+        kw = (dict(impl="pallas", interpret=self.interpret,
+                   n_actions=self.n_actions, mesh=self.mesh)
+              if self.kernel else dict(impl="ref"))
+        return fused_tabular_update(q, s, a, r, s2, alpha=alpha,
+                                    gamma=gamma, **kw)
+
+
+def tabular_path(impl: str, n_states: int, n_actions: int, mesh=None,
+                 per_shard: bool = False) -> TabularPath:
+    """The ``TabularPath`` ``impl`` resolves to (``resolve_rl_impl``);
+    ``per_shard``: ``mesh`` splits the table along its cells."""
+    resolved = resolve_rl_impl(impl, mesh, per_shard=per_shard)
+    block_mesh = mesh if per_shard and resolved != "ref" else None
+    return TabularPath(resolved, n_states, n_actions, block_mesh)
 
 
 def dqn_head_parts(resolved: str, threshold: float) -> dict:
@@ -214,13 +277,10 @@ def fused_tabular_update(q, s, a, r, s2, *, alpha: float, gamma: float,
     (``shard_map``), since GSPMD cannot partition a ``pallas_call``.
     Each block is padded to ``bc`` on its own."""
     if mesh is not None:
-        per_block = functools.partial(
+        return per_block(functools.partial(
             fused_tabular_update, alpha=alpha, gamma=gamma, impl=impl,
-            n_actions=n_actions, bc=bc, interpret=interpret)
-        cells = P(mesh.axis_names)
-        return jax.shard_map(per_block, mesh=mesh, in_specs=cells,
-                             out_specs=cells, check_vma=False)(
-                                 q, s, a, r, s2)
+            n_actions=n_actions, bc=bc, interpret=interpret), mesh)(
+                q, s, a, r, s2)
     if impl == "ref":
         if n_actions is not None:
             raise ValueError("the ref path takes a logical table")

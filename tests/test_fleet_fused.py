@@ -1,12 +1,13 @@
-"""End-to-end training equivalence across the RL impl seam (ISSUE-10).
+"""End-to-end training equivalence across the RL impl seam.
 
 The fused hot path (``impl='pallas'`` and friends) must train the SAME
-agent as the legacy unfused step: both agents consume RNG identically
-(see ``FleetQLearning._explore``), so tabular trajectories are
-bit-identical and DQN trajectories match to reduction-order tolerance.
-Runs entirely on CPU — ``'pallas'`` resolves to the fused-jnp
-formulation here, and ``'pallas_interpret'`` forces the real kernel
-through the Pallas interpreter on a tiny fleet.
+agent as the unfused composition. Every path draws the same random
+numbers, so the tabular agent is bit-identical when its update op
+(``ops.fused_tabular_update``) is swapped for the unfused
+gather/max/scatter/argmax chain, and ``FleetDQN``'s fused head matches
+its legacy head (``impl='xla'``) to reduction-order tolerance. Runs entirely on CPU — ``'pallas'``
+resolves to the fused-jnp formulation here, and ``'pallas_interpret'``
+forces the real kernel through the Pallas interpreter on a tiny fleet.
 """
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ import pytest
 
 from repro.fleet import (FleetConfig, FleetDQN, FleetDQNConfig,
                          FleetQConfig, FleetQLearning, SyntheticSource)
+from test_kernels import _naive_tabular
 
 
 def _source(cells=32, users=2, seed=0):
@@ -28,13 +30,32 @@ def _tabular(impl, cells=32, **kw):
                           impl=impl, **kw)
 
 
-def test_tabular_fused_training_bit_identical_to_xla():
+def _unfused(monkeypatch):
+    """From here on, ``ops.fused_tabular_update`` is the unfused
+    gather/max/scatter/argmax composition (``test_kernels``'s oracle),
+    the seam the benchmark's faults use; returns the list its calls
+    are logged in."""
+    from repro.kernels import ops
+    calls = []
+
+    def naive(*args, **kw):
+        calls.append(kw)
+        return _naive_tabular(*args, **kw)
+
+    monkeypatch.setattr(ops, "fused_tabular_update", naive)
+    return calls
+
+
+def test_tabular_fused_training_bit_identical_to_xla(monkeypatch):
     """40 scanned steps: Q-table, counts, and greedy decisions from the
-    fused path are bit-identical to the legacy unfused step."""
-    a, b = _tabular("xla"), _tabular("pallas")
-    assert b._op_impl != "xla"       # the seam actually switched paths
+    fused path are bit-identical to the same agent whose update is the
+    unfused composition."""
+    a = _tabular("pallas")
     a.run(40)
+    calls = _unfused(monkeypatch)
+    b = _tabular("pallas")
     b.run(40)
+    assert calls                     # the seam actually switched ops
     np.testing.assert_array_equal(np.asarray(a.q), np.asarray(b.q))
     np.testing.assert_array_equal(np.asarray(a.counts),
                                   np.asarray(b.counts))
@@ -46,16 +67,20 @@ def test_tabular_fused_training_bit_identical_to_xla():
                                                  rel=1e-6)
 
 
-def test_tabular_stepwise_bit_identical_across_impls():
+def test_tabular_stepwise_bit_identical_across_impls(monkeypatch):
     """The single-step path (which re-gathers greedy instead of carrying
-    it) is also bit-identical across the seam. Stepwise and scanned
-    runs differ from EACH OTHER on either impl (host-float vs in-carry
-    f32 epsilon decay, a pre-existing property) — the seam guarantee is
-    within each mode."""
-    a, b = _tabular("xla", cells=8), _tabular("pallas", cells=8)
+    it) is also bit-identical to the unfused composition. Stepwise and
+    scanned runs differ from EACH OTHER (host-float vs in-carry f32
+    epsilon decay, a pre-existing property) — the guarantee is within
+    each mode."""
+    a = _tabular("pallas", cells=8)
     for _ in range(10):
         a.step()
+    calls = _unfused(monkeypatch)
+    b = _tabular("pallas", cells=8)
+    for _ in range(10):
         b.step()
+    assert calls
     np.testing.assert_array_equal(np.asarray(a.q), np.asarray(b.q))
     np.testing.assert_array_equal(np.asarray(a.counts),
                                   np.asarray(b.counts))
@@ -64,10 +89,11 @@ def test_tabular_stepwise_bit_identical_across_impls():
 def test_tabular_interpret_kernel_training_matches_xla():
     """The real Pallas kernel (interpret mode) on a ragged fleet (13
     cells, not a block's multiple): two scanned ``run`` calls and one
-    ``step``, identical trajectories up to the kernel's fma-contraction
-    ulp. The scan carries the table in the kernel's layout, and
-    ``agent.q`` keeps its logical shape between calls."""
-    a = _tabular("xla", cells=13)
+    ``step``, identical trajectories to the fused-jnp ``ref`` up to the
+    kernel's fma-contraction ulp. The scan carries the table in the
+    kernel's layout, and ``agent.q`` keeps its logical shape between
+    calls."""
+    a = _tabular("ref", cells=13)
     b = _tabular("pallas_interpret", cells=13)
     shape = (13, b.n_states, b.n_actions)
     assert b.q.shape == shape
@@ -82,9 +108,10 @@ def test_tabular_interpret_kernel_training_matches_xla():
                                       np.asarray(b.counts))
 
 
-def test_tabular_unknown_impl_raises():
+@pytest.mark.parametrize("impl", ["cuda", "xla"])
+def test_tabular_unknown_impl_raises(impl):
     with pytest.raises(ValueError, match="unknown impl"):
-        _tabular("cuda")
+        _tabular(impl)
 
 
 def _dqn(impl, threshold=85.0, cells=16):

@@ -151,23 +151,18 @@ def test_fused_impl_sharded_training_bit_parity():
     (GSPMD cannot partition ``pallas_call``; see
     ``kernels.ops.resolve_rl_impl``) — per-cell elementwise + reduces
     along the unsharded action axis, so a sharded fused run is
-    bit-identical to the single-device fused run AND to the legacy
-    unfused step."""
+    bit-identical to the single-device fused run."""
     from repro.kernels import ops
     cfg = _full_cfg(8 * NDEV)
     single = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
                             seed=3, impl="pallas")
     meshed = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
                             seed=3, impl="pallas", mesh=_mesh())
-    legacy = FleetQLearning(SyntheticSource(cfg), cfg=FleetQConfig(),
-                            seed=3, impl="xla", mesh=_mesh())
     assert ops.resolve_rl_impl("pallas", meshed.mesh) == "ref"
     assert meshed._op_impl == "ref"
-    for ag in (single, meshed, legacy):
+    for ag in (single, meshed):
         ag.run(40)
     np.testing.assert_array_equal(np.asarray(single.q),
-                                  np.asarray(meshed.q))
-    np.testing.assert_array_equal(np.asarray(legacy.q),
                                   np.asarray(meshed.q))
     np.testing.assert_array_equal(np.asarray(single.counts),
                                   np.asarray(meshed.counts))
@@ -249,7 +244,7 @@ def test_tpu_mesh_resolves_the_kernel_for_tabular_only(monkeypatch):
                                                    shard_local=True)),
                          cfg=FleetQConfig(), seed=3, mesh=mesh)
     assert tab._op_impl == "pallas"
-    assert tab._op_kwargs == {"impl": "pallas", "interpret": False}
+    assert not tab.path.interpret and tab.path.mesh is mesh
     assert tab.update_path == "pallas_per_shard"
     dqn = FleetDQN(SyntheticSource(FleetConfig(cells=8 * NDEV, users=2)),
                    cfg=FleetDQNConfig(), seed=5, mesh=mesh)
@@ -270,7 +265,7 @@ def test_zeros_are_made_on_each_device():
     assert not np.asarray(agent.q).any()
 
 
-@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
 def test_second_run_under_the_mesh_compiles_nothing(impl):
     """Every leaf of the placed scenario, its step counter too, comes
     back from the scan in the layout it went in with, so the next

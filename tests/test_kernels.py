@@ -125,9 +125,11 @@ def test_assoc_scan_matches_sequential_oracle():
 ALPHA, GAMMA = 0.9, 0.1
 
 
-def _naive_tabular(q, s, a, r, s2, alpha=ALPHA, gamma=GAMMA):
-    """The legacy unfused composition (population.py's xla step +
-    next-step gather/argmax), the semantic oracle for the fused op."""
+def _naive_tabular(q, s, a, r, s2, alpha=ALPHA, gamma=GAMMA, **_):
+    """The unfused composition (gather, max, scatter, then the next
+    step's gather and argmax), the semantic oracle for the fused op. It
+    takes and ignores the op's other keywords, so that it can stand in
+    for ``ops.fused_tabular_update`` on the ``ref`` path."""
     cells = jnp.arange(q.shape[0])
     td = r + gamma * q[cells, s2].max(-1) - q[cells, s, a]
     q_new = q.at[cells, s, a].add(alpha * td)
@@ -274,16 +276,97 @@ def test_tabular_block_cells_ignores_states():
 
 
 def test_resolve_rl_impl_gating():
-    assert ops.resolve_rl_impl("xla") == "xla"
     assert ops.resolve_rl_impl("ref") == "ref"
     assert ops.resolve_rl_impl("pallas_interpret") == "pallas_interpret"
     # GSPMD cannot partition pallas_call: a mesh forces the fused-jnp ref
     assert ops.resolve_rl_impl("pallas", mesh=object()) == "ref"
     assert ops.resolve_rl_impl("pallas") in ("pallas", "ref")
-    with pytest.raises(ValueError, match="unknown impl"):
-        ops.resolve_rl_impl("cuda")
-    with pytest.raises(ValueError, match="no fused op path"):
-        ops.rl_op_kwargs("xla")
+    for impl in ("cuda", "xla"):
+        with pytest.raises(ValueError, match="unknown impl"):
+            ops.resolve_rl_impl(impl)
+
+
+@pytest.mark.parametrize("impl,meshed,per_shard,tpu,want", [
+    ("ref", False, False, False, "ref"),
+    ("ref", True, True, True, "ref"),
+    ("pallas", False, False, False, "ref"),          # no TPU backend
+    ("pallas", False, False, True, "pallas"),
+    ("pallas", True, False, True, "ref"),            # table not split
+    ("pallas", True, True, True, "pallas_per_shard"),
+    ("pallas_interpret", False, False, False, "pallas_interpret"),
+    ("pallas_interpret", True, False, False, "pallas_interpret"),
+    ("pallas_interpret", True, True, False, "pallas_interpret_per_shard"),
+])
+def test_tabular_path_names_each_impl_and_mesh(monkeypatch, impl, meshed,
+                                               per_shard, tpu, want):
+    """``tabular_path`` resolves ``impl`` under a mesh or none, and the
+    path's name is what the ``fleet.run`` span reports; the kernel runs
+    per block exactly where the name says so. Nothing is compiled: the
+    TPU backend is only named."""
+    from jax.sharding import Mesh
+    if tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("fleet",)) if meshed \
+        else None
+    path = ops.tabular_path(impl, 9, 10, mesh, per_shard=per_shard)
+    assert path.name == want
+    assert path.kernel == (not want.startswith("ref"))
+    assert path.interpret == (impl == "pallas_interpret")
+    assert path.mesh is (mesh if want.endswith("_per_shard") else None)
+
+
+def _check_tabular_path_round_trip(impl: str, devices: int) -> None:
+    """``leave(enter(q)) == q`` and ``rows(enter(q), s) == q[cells, s]``
+    for a ragged 13-cell block a device; on ``devices`` > 1 the kernel's
+    layout changes run per block of a ``('fleet',)`` mesh, and the
+    table stays split along its cells."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    assert jax.device_count() >= devices
+    cells = 13 * devices
+    q, s, _, _, _ = _tabular_case(cells)
+    mesh = None
+    if devices > 1:
+        mesh = Mesh(np.asarray(jax.devices()[:devices]), ("fleet",))
+        q = jax.device_put(q, NamedSharding(mesh, P("fleet")))
+        s = jax.device_put(s, NamedSharding(mesh, P("fleet")))
+    path = ops.tabular_path(impl, *q.shape[1:], mesh, per_shard=True)
+    assert path.kernel == (impl != "ref")
+    inner = jax.jit(path.enter)(q)
+    if path.kernel:
+        assert inner.shape == (cells, 16, 128)   # 9 -> 16 rows, 10 -> 128
+    if mesh is not None:
+        assert path.name == impl + "_per_shard"
+        assert inner.sharding.spec[0] == "fleet"
+    np.testing.assert_array_equal(np.asarray(jax.jit(path.rows)(inner, s)),
+                                  np.asarray(q)[np.arange(cells),
+                                                np.asarray(s)])
+    np.testing.assert_array_equal(np.asarray(jax.jit(path.leave)(inner)),
+                                  np.asarray(q))
+
+
+@pytest.mark.parametrize("impl,devices", [("ref", 1),
+                                          ("pallas_interpret", 1),
+                                          ("pallas_interpret", 2)])
+def test_tabular_path_round_trip(impl, devices):
+    """The table's way into and out of the update's layout, and the row
+    read in between. Two devices are forced on the CPU in a child
+    process (``--xla_force_host_platform_device_count``)."""
+    if devices == 1:
+        _check_tabular_path_round_trip(impl, devices)
+        return
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(here, "..", "src"), here]))
+    code = ("import test_kernels; test_kernels."
+            f"_check_tabular_path_round_trip({impl!r}, {devices})")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stdout + res.stderr
 
 
 # ------------------------------------------------- fused DQN head ---------

@@ -185,8 +185,6 @@ def _bench_payload(**overrides):
         "slo_attainment_measured": 0.9, "slo_attainment_predicted": 1.0,
         "p99_ms": 2000.0, "windowed_overhead_x": 1.0,
         "rl_fused_tabular_steps_per_s": 8e5,
-        "rl_unfused_tabular_steps_per_s": 4e5,
-        "rl_fused_tabular_speedup_x": 2.0,
         "rl_fused_dqn_steps_per_s": 9e4,
         "rl_unfused_dqn_steps_per_s": 8e4,
         "rl_fused_dqn_speedup_x": 1.15,
@@ -241,20 +239,20 @@ def test_benchgate_degraded_slo_attainment_fails(tmp_path):
 
 
 def test_benchgate_fused_speedup_floor(tmp_path):
-    """ISSUE-10: a run whose fused/unfused ratio collapses below the
-    absolute floor exits 1 — fused regressing to parity with the legacy
-    path must fail the build even if raw throughput looks fine."""
+    """A run whose fused/legacy DQN head ratio collapses below the
+    absolute floor exits 1 — the fused head regressing to parity with
+    the legacy head must fail the build even if raw throughput looks
+    fine."""
     base = _write(tmp_path / "base.json", _bench_payload())
     bad = _write(tmp_path / "bad.json", _bench_payload(
-        rl_fused_tabular_speedup_x=1.1,   # below the 1.7 floor
         rl_fused_dqn_speedup_x=0.9))      # fused slower than legacy
     res = _gate(base, bad)
     assert res.returncode == 1, res.stdout + res.stderr
-    assert "2 regression(s)" in res.stdout
-    assert "rl_fused_tabular_speedup_x" in res.stdout
+    assert "1 regression(s)" in res.stdout
+    assert "rl_fused_dqn_speedup_x" in res.stdout
     # at-floor still passes even below the baseline's recorded ratio
     ok = _write(tmp_path / "ok.json", _bench_payload(
-        rl_fused_tabular_speedup_x=1.75, rl_fused_dqn_speedup_x=1.03))
+        rl_fused_dqn_speedup_x=1.03))
     assert _gate(base, ok).returncode == 0
 
 

@@ -97,7 +97,7 @@ def test_tabular_scan_keeps_kernel_name_under_update_scope(one_chip):
     agent = FleetQLearning(SyntheticSource(FleetConfig(cells=1024,
                                                        users=USERS)),
                            cfg=FleetQConfig())
-    agent._op_kwargs = ops.rl_op_kwargs("pallas")
+    agent.path = ops.TabularPath("pallas", agent.n_states, agent.n_actions)
     run = jax.jit(agent._make_run(), static_argnums=(6,),
                   donate_argnums=(0, 1))
     args = jax.tree.map(
@@ -125,7 +125,7 @@ def _scan(one_chip, cells, **cfg):
                                                        users=USERS)),
                            cfg=FleetQConfig(**cfg))
     agent.metrics = fleet_metrics(cells, "tabular")
-    agent._op_kwargs = ops.rl_op_kwargs("pallas")
+    agent.path = ops.TabularPath("pallas", agent.n_states, agent.n_actions)
     run = jax.jit(agent._make_run(), static_argnums=(6,),
                   donate_argnums=(0, 1))
     args = jax.tree.map(
@@ -196,8 +196,8 @@ def _sharded_scan(topo, kernel: bool):
                            impl="ref")
     agent.source.attach_mesh(mesh)
     if kernel:
-        agent._op_kwargs = ops.rl_op_kwargs("pallas")
-        agent._block_mesh = mesh
+        agent.path = ops.TabularPath("pallas", agent.n_states,
+                                     agent.n_actions, mesh)
     run = jax.jit(agent._make_run(), static_argnums=(6,),
                   donate_argnums=(0, 1))
     s = agent.scen
