@@ -11,10 +11,14 @@ Design constraints, in order:
    (``fleet.shard``, CHANGES.md) only holds for per-cell elementwise
    work plus integer cross-device sums. Each metric therefore carries a
    ``lanes`` axis (lanes = cells for per-cell signals): updates are
-   elementwise along lanes, histograms are integer scatter-adds, and
-   the only cross-lane reduction — producing the scalar mean/std/min/
-   max — happens host-side in float64 numpy at ``summary()`` time.
-   A sharded accumulator (lane leaves sharded along the fleet axis via
+   elementwise along lanes, histograms are integer compare-and-sums
+   (``bins * n`` compares for ``n`` samples, see :func:`_bin_counts`),
+   and the only cross-lane float reduction — producing the scalar
+   mean/std/min/max — happens host-side in float64 numpy at
+   ``summary()`` time. A histogram's count is a sum of integers, so
+   however the partitioner splits the samples over devices and
+   all-reduces the partial counts, it is the same number. A sharded
+   accumulator (lane leaves sharded along the fleet axis via
    :meth:`place`) is bit-identical to the single-device one.
 2. *Plain merge.* ``merge`` is plain ``+`` on count/total/sumsq/hist
    and ``min``/``max`` on extrema — associative, and exact on the
@@ -51,6 +55,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import timeline
+
+
+def _bin_counts(idx: jnp.ndarray, bins: int) -> jnp.ndarray:
+    """Count ``idx`` (ints in ``[0, bins)``) into ``bins`` i32 bins.
+
+    A compare-and-sum with the bins on the leading axis and the samples
+    on the trailing one: XLA fuses compare, convert and reduce into one
+    reduction and never materialises the ``(bins, n)`` mask. It costs
+    ``bins * n`` compares, which at every ``MetricDef`` in the repo (32
+    or 64 bins) is far cheaper on a TPU than a scatter-add, whose
+    updates to shared bins run one after another. The counts equal the
+    scatter's exactly.
+    """
+    return (idx[None, :] == jnp.arange(bins, dtype=idx.dtype)[:, None]
+            ).sum(1, dtype=jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,7 +212,7 @@ class MetricsAccumulator:
                 "sumsq": d["sumsq"] + (x * x).sum(-1),
                 "mn": jnp.minimum(d["mn"], x.min(-1)),
                 "mx": jnp.maximum(d["mx"], x.max(-1)),
-                "hist": d["hist"].at[idx.ravel()].add(1),
+                "hist": d["hist"] + _bin_counts(idx.ravel(), df.bins),
                 # integer cross-lane sums — the second op class the
                 # sharding discipline admits (bit-exact psum)
                 "underflow": d["underflow"]
@@ -259,8 +278,8 @@ class MetricsAccumulator:
         histograms, under/overflow counters, the step counter, and
         single-lane leaves go through ``replicate_fn``. With this
         placement the jitted update partitions into per-device
-        elementwise work plus integer scatters/sums — bit-identical to
-        the single-device program.
+        elementwise work plus integer compare-and-sums — bit-identical
+        to the single-device program.
         """
         replicated = ("hist", "underflow", "overflow")
         data = {}

@@ -85,6 +85,48 @@ def test_metrics_histogram_merge_equals_concat_then_bin():
     np.testing.assert_array_equal(merged["hist"], ref)
 
 
+@pytest.mark.parametrize("bins", [1, 32, 64])
+@pytest.mark.parametrize("lanes", [1, 1024])
+@pytest.mark.parametrize("k", [1, 3])
+def test_metrics_hist_counts_equal_bincount_and_scatter(bins, lanes, k):
+    """``hist`` after two updates is the bincount of the clipped bin
+    indices, and equals the scatter-add formulation ``.at[idx].add(1)``,
+    for samples inside ``[lo, hi)`` (bin edges included), below ``lo``,
+    at ``hi`` and above it. ``hi - lo`` is 2 and ``bins`` a power of two
+    (or 1), so the bin arithmetic is exact in float32 on either side."""
+    lo, hi = -2.0, 0.0
+    df = MetricDef(lo=lo, hi=hi, bins=bins, lanes=lanes)
+    rng = np.random.default_rng(bins * 10 + lanes + k)
+    special = np.array([lo, hi, -3.0, 0.5, lo + 2.0 / bins, hi - 2.0**-20],
+                       np.float32)
+    xs = []
+    for _ in range(2):
+        x = rng.uniform(lo - 0.5, hi + 0.5,
+                        size=(lanes, k)).astype(np.float32)
+        x.ravel()[:special.size] = special[:x.size]
+        xs.append(x)
+    acc = MetricsAccumulator.create({"m": df})
+    update = jax.jit(lambda a, x: a.update({"m": x}))
+    for x in xs:
+        acc = update(acc, jnp.asarray(x))
+    hist = np.asarray(acc.data["m"]["hist"])
+    assert hist.dtype == np.int32
+
+    flat = np.concatenate([x.ravel() for x in xs])
+    scale = np.float32(bins / (hi - lo))
+    idx = np.clip(((flat - np.float32(lo)) * scale).astype(np.int32),
+                  0, bins - 1)
+    np.testing.assert_array_equal(hist, np.bincount(idx, minlength=bins))
+
+    jidx = jnp.clip(((jnp.asarray(flat) - lo) * (bins / (hi - lo)))
+                    .astype(jnp.int32), 0, bins - 1)
+    scatter = jnp.zeros((bins,), jnp.int32).at[jidx].add(1)
+    np.testing.assert_array_equal(hist, np.asarray(scatter))
+    s = acc.summary()["m"]
+    assert s["underflow"] == int((flat < lo).sum())
+    assert s["overflow"] == int((flat >= hi).sum())
+
+
 def test_metrics_chunked_merge_matches_single_stream():
     """merge(chunk1, chunk2) == one stream: exact on count/hist/extrema,
     reassociation-ULP close on the float sums (the CHANGES.md caveat)."""

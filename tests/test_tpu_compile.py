@@ -137,12 +137,18 @@ def _scan(one_chip, cells, **cfg):
     return agent, run.lower(*args, 8).compile()
 
 
-def test_tabular_scan_at_benchmark_size_holds_one_padded_table(one_chip):
+@pytest.fixture(scope="module")
+def bench_scan(one_chip):
+    """``tabular_train``'s scan, compiled once for the module."""
+    return _scan(one_chip, BENCH_CELLS)
+
+
+def test_tabular_scan_at_benchmark_size_holds_one_padded_table(bench_scan):
     """At the benchmark's 131,072 x 36 x 243 the scan carries the table
     in the kernel's layout, padded to 40 x 256: its temporaries are that
     one table and at most 10% more (the pieces of the layout changes
     around the scan, the step's own arrays)."""
-    agent, compiled = _scan(one_chip, BENCH_CELLS)
+    agent, compiled = bench_scan
     assert (agent.n_states, agent.n_actions) == (STATES, ACTIONS)
     padded = BENCH_CELLS * 40 * 256 * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * padded
@@ -229,7 +235,14 @@ def test_sharded_scan_at_benchmark_size_fits_four_chips(topo):
     assert "all-reduce" in text
 
 
-def test_sharded_kernel_scan_at_benchmark_size_runs_one_kernel_a_chip(topo):
+@pytest.fixture(scope="module")
+def mesh_kernel_scan(topo):
+    """``sharded_train_4chip``'s scan with the kernel, compiled once."""
+    return _sharded_scan(topo, kernel=True)
+
+
+def test_sharded_kernel_scan_at_benchmark_size_runs_one_kernel_a_chip(
+        mesh_kernel_scan):
     """The same four-chip scan with the kernel path forced, as a TPU
     backend resolves ``impl="pallas"`` under a mesh: the ``tabular_rl``
     kernel runs once a chip on its 65,536 cells under ``shard_map``,
@@ -239,7 +252,7 @@ def test_sharded_kernel_scan_at_benchmark_size_runs_one_kernel_a_chip(topo):
     more (no whole-shard relayouts)."""
     import re
 
-    compiled = _sharded_scan(topo, kernel=True)
+    compiled = mesh_kernel_scan
     q_in = compiled.input_shardings[0][0]
     assert q_in.shard_shape((MESH_CELLS, STATES, ACTIONS))[0] \
         == MESH_CELLS // MESH_CHIPS
@@ -253,3 +266,26 @@ def test_sharded_kernel_scan_at_benchmark_size_runs_one_kernel_a_chip(topo):
     padded_shard = MESH_CELLS // MESH_CHIPS * 40 * 256 * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 1.1 * padded_shard
+
+
+@pytest.mark.parametrize("scan", ["bench_scan", "mesh_kernel_scan"])
+def test_benchmark_scans_count_histograms_without_scatter(request, scan):
+    """Both benchmark scans count the three per-cell telemetry
+    histograms (``reward``, ``mean_ms``, ``td_abs``; 32 bins) as one
+    ``s32[32]`` reduction each under ``fleet.telemetry``, and no op
+    under that scope is a scatter: a scatter-add over 131,072 indices
+    into 32 bins runs one update after another on a TPU. On the mesh
+    the partial counts are all-reduced."""
+    import re
+
+    compiled = request.getfixturevalue(scan)
+    if scan == "bench_scan":
+        compiled = compiled[1]
+    text = compiled.as_text()
+    assert "fleet.telemetry/scatter" not in text
+    counts = re.findall(
+        r"= s32\[32\]\{[^}]*\} fusion\(.*op_name=\"jit\(run\)/while/body/"
+        r"closed_call/fleet\.telemetry/reduce_sum\"", text)
+    assert len(counts) == 3
+    if scan == "mesh_kernel_scan":
+        assert "all-reduce" in text
